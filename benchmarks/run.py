@@ -31,6 +31,8 @@ def main() -> None:
     ap.add_argument("--only", nargs="*", default=None)
     ap.add_argument("--out", default="experiments/bench_results.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from .common import BenchSettings
     s = BenchSettings(steps=60 if args.quick else 240,

@@ -86,21 +86,25 @@ def stack_experts_for_decode(expert_params):
 
     Returns ``(stacked, in_axes)`` where ``in_axes`` is the per-leaf vmap
     axis tree to pass to ``jax.vmap``.
+
+    Each leaf is stacked straight into its decode-layout axis, one leaf
+    at a time, with no K-major intermediate to transpose. Experts held on
+    the device stay there beside the stacked copy (two full-width
+    InternVL2-2B experts: 7 GiB + 7 GiB, more than one 16 GB chip has
+    room for next to anything else); experts passed as host (numpy)
+    arrays are uploaded one leaf at a time, so the device holds the
+    stacked copy plus one leaf's K inputs.
     """
-    stacked = stack_expert_params(expert_params)
-    axes = jax.tree.map(lambda _: 0, stacked)
-
-    def layer_major(sub):
-        return (jax.tree.map(lambda a: jnp.moveaxis(a, 0, 1), sub),
-                jax.tree.map(lambda _: 1, sub))
-
-    if isinstance(stacked, dict) and "blocks" in stacked:
-        stacked, axes = dict(stacked), dict(axes)
-        stacked["blocks"], axes["blocks"] = layer_major(stacked["blocks"])
-        if "encoder" in stacked:          # audio enc-dec: encoder stack too
-            enc, eaxes = dict(stacked["encoder"]), dict(axes["encoder"])
-            enc["blocks"], eaxes["blocks"] = layer_major(enc["blocks"])
-            stacked["encoder"], axes["encoder"] = enc, eaxes
+    axes = jax.tree.map(lambda _: 0, expert_params[0])
+    if isinstance(axes, dict) and "blocks" in axes:
+        axes = dict(axes)
+        axes["blocks"] = jax.tree.map(lambda _: 1, axes["blocks"])
+        if "encoder" in axes:             # audio enc-dec: encoder stack too
+            axes["encoder"] = dict(axes["encoder"])
+            axes["encoder"]["blocks"] = jax.tree.map(
+                lambda _: 1, axes["encoder"]["blocks"])
+    stacked = jax.tree.map(lambda ax, *leaves: jnp.stack(leaves, axis=ax),
+                           axes, *expert_params)
     return stacked, axes
 
 

@@ -5,18 +5,23 @@ Grid = (batch, kv_heads, kv_blocks). All ``group = H/KV`` query heads that
 share a KV head are processed together as a (group, dh) tile — the natural
 GQA layout on the MXU (the group dim rides the sublane axis). Position
 masking (including the ring-buffer validity rule for sliding-window caches)
-is computed from a prefetched per-batch position scalar.
+is computed from a per-batch position scalar held in SMEM (a
+scalar-prefetch operand).
 
-Two cache layouts share the kernel body:
+Every K/V block is (positions, dh) in its last two dims — the TPU tiling
+rule wants those divisible by (8, 128) or equal to the array's own, and a
+head axis among them would give a block of 1 against KV. Two cache layouts
+share the kernel body:
 
-* contiguous — K/V are (B, S, KV, dh) slot rows, the ki-th grid step reads
-  the ki-th sequence block of row b directly;
-* paged — K/V live in a shared (P, block, KV, dh) block pool and the ki-th
-  grid step reads physical block ``block_tables[b, ki]``: the per-slot
-  block table is a scalar-prefetch operand, so the index map resolves the
-  indirection at DMA-issue time and the body never sees it (the classic
-  paged-attention gather). Unallocated table entries point at the reserved
-  scratch block 0 and are killed by the position mask.
+* contiguous — K/V are (B, S, KV, dh) slot rows, transposed head-major to
+  (B, KV, S, dh) by the wrapper; the ki-th grid step reads the ki-th
+  sequence block of row b;
+* paged — K/V live in a shared head-major (P, KV, block, dh) block pool
+  and the ki-th grid step reads physical block ``block_tables[b, ki]``:
+  the per-slot block table is a scalar-prefetch operand, so the index map
+  resolves the indirection at DMA-issue time and the body never sees it
+  (the classic paged-attention gather). Unallocated table entries point at
+  the reserved scratch block 0 and are killed by the position mask.
 """
 from __future__ import annotations
 
@@ -37,9 +42,9 @@ def _accum_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
     """Online-softmax accumulation of one KV block — the single source of
     the masking fence and the m/l/acc rescaling recurrence, shared by the
     contiguous and paged kernels so their numerics can never diverge."""
-    q = q_ref[0, 0, :, :].astype(jnp.float32)          # (group, dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (block, dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)          # (block, dv)
+    q = q_ref[0, 0].astype(jnp.float32)                # (group, dh)
+    k = k_ref[0, 0].astype(jnp.float32)                # (block, dh)
+    v = v_ref[0, 0].astype(jnp.float32)                # (block, dv)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -63,6 +68,7 @@ def _accum_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, *,
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *,
                    scale: float, block_k: int, window: int, s_cache: int):
+    b = pl.program_id(0)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -73,7 +79,7 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     _accum_block(q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, scale=scale,
-                 block=block_k, ki=ki, pos=pos_ref[0], window=window,
+                 block=block_k, ki=ki, pos=pos_ref[b], window=window,
                  s_cache=s_cache)
 
     @pl.when(ki == nk - 1)
@@ -85,7 +91,11 @@ def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 def decode_attention(q: Array, k: Array, v: Array, pos: Array, *,
                      window: int = 0, block_k: int = 256,
                      interpret: bool = False) -> Array:
-    """q: (B,H,dh); k,v: (B,S,KV,dh); pos: (B,) int32 → (B,H,dh)."""
+    """q: (B,H,dh); k,v: (B,S,KV,dh); pos: (B,) int32 → (B,H,dh).
+
+    The cache is read head-major, so this wrapper transposes K/V to
+    (B, KV, S, dh) first: a full copy of the cache per call. The paged
+    kernels below keep their pool head-major and copy nothing."""
     B, H, dh = q.shape
     S, KV = k.shape[1], k.shape[2]
     assert H % KV == 0
@@ -99,28 +109,32 @@ def decode_attention(q: Array, k: Array, v: Array, pos: Array, *,
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_k=block_k,
                                window=window, s_cache=S)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                        # pos
         grid=(B, KV, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,)),            # pos
             pl.BlockSpec((1, 1, group, dh),
-                         lambda b, h, ki: (b, h, 0, 0)),          # q
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b, h, ki: (b, ki, h, 0)),         # k
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b, h, ki: (b, ki, h, 0)),         # v
+                         lambda b, h, ki, pos_r: (b, h, 0, 0)),   # q
+            pl.BlockSpec((1, 1, block_k, dh),
+                         lambda b, h, ki, pos_r: (b, h, ki, 0)),  # k
+            pl.BlockSpec((1, 1, block_k, dh),
+                         lambda b, h, ki, pos_r: (b, h, ki, 0)),  # v
         ],
         out_specs=pl.BlockSpec((1, 1, group, dh),
-                               lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, group, dh), q.dtype),
+                               lambda b, h, ki, pos_r: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, dh), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, group, dh), q.dtype),
         interpret=interpret,
-    )(pos.astype(jnp.int32), qg, k, v)
+    )(pos.astype(jnp.int32), qg, jnp.swapaxes(k, 1, 2),
+      jnp.swapaxes(v, 1, 2))
     return out.reshape(B, H, dh)
 
 
@@ -210,9 +224,9 @@ def _chunk_prefill_kernel(start_ref, bt_ref, q_ref, *refs,
 
         @pl.when(live)
         def _accum(j=j, ki=ki):
-            q = q_ref[0, :, :].astype(jnp.float32)       # (C·group, dh)
-            k = k_refs[j][0, :, 0, :].astype(jnp.float32)  # (block, dh)
-            v = v_refs[j][0, :, 0, :].astype(jnp.float32)
+            q = q_ref[0].astype(jnp.float32)             # (C·group, dh)
+            k = k_refs[j][0, 0].astype(jnp.float32)      # (block, dh)
+            v = v_refs[j][0, 0].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -242,7 +256,7 @@ def chunk_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
                             blocks_per_step: int = 1,
                             interpret: bool = False) -> Array:
     """q: (C,H,dh) one request's chunk queries; k_pool,v_pool:
-    (P,block,KV,dh) with the chunk's K/V already scattered in; start: ()
+    (P,KV,block,dh) with the chunk's K/V already scattered in; start: ()
     int32 absolute position of chunk row 0; block_table: (NB,) int32 →
     (C,H,dh).
 
@@ -257,7 +271,7 @@ def chunk_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
     position fence.
     """
     C, H, dh = q.shape
-    block, KV = k_pool.shape[1], k_pool.shape[2]
+    KV, block = k_pool.shape[1], k_pool.shape[2]
     NB = block_table.shape[0]
     assert H % KV == 0
     group = H // KV
@@ -278,8 +292,8 @@ def chunk_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
             ki = jnp.minimum(jnp.minimum(kc * bps + j,
                                          (start_r[0] + C - 1) // block),
                              NB - 1)
-            return (bt_r[ki], 0, h, 0)
-        return pl.BlockSpec((1, block, 1, dh), imap)
+            return (bt_r[ki], h, 0, 0)
+        return pl.BlockSpec((1, 1, block, dh), imap)
 
     kernel = functools.partial(_chunk_prefill_kernel, scale=scale,
                                block=block, group=group, C=C,
@@ -351,9 +365,9 @@ def _paged_verify_kernel(pos_ref, bt_ref, q_ref, *refs,
 
         @pl.when(live)
         def _accum(j=j, ki=ki):
-            q = q_ref[0, 0, :, :].astype(jnp.float32)    # (L·group, dh)
-            k = k_refs[j][0, :, 0, :].astype(jnp.float32)  # (block, dh)
-            v = v_refs[j][0, :, 0, :].astype(jnp.float32)
+            q = q_ref[0, 0].astype(jnp.float32)          # (L·group, dh)
+            k = k_refs[j][0, 0].astype(jnp.float32)      # (block, dh)
+            v = v_refs[j][0, 0].astype(jnp.float32)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
@@ -384,7 +398,7 @@ def paged_verify_attention(q: Array, k_pool: Array, v_pool: Array,
                            interpret: bool = False) -> Array:
     """q: (B,L,H,dh) span queries (row ℓ of slot b sits at absolute
     position ``pos[b] + ℓ``, its K/V already scattered into the pool);
-    k_pool,v_pool: (P,block,KV,dh); pos: (B,) int32; block_tables: (B,NB)
+    k_pool,v_pool: (P,KV,block,dh); pos: (B,) int32; block_tables: (B,NB)
     int32 → (B,L,H,dh).
 
     Grid = (batch, kv_heads, ⌈NB / blocks_per_step⌉), one (L·group, dh)
@@ -398,7 +412,7 @@ def paged_verify_attention(q: Array, k_pool: Array, v_pool: Array,
     are not supported: the scheduler only routes windowless models here.
     """
     B, L, H, dh = q.shape
-    P, block, KV = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    P, KV, block = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     NB = block_tables.shape[1]
     assert H % KV == 0
     group = H // KV
@@ -417,8 +431,8 @@ def paged_verify_attention(q: Array, k_pool: Array, v_pool: Array,
             ki = jnp.minimum(jnp.minimum(kc * bps + j,
                                          (pos_r[b] + L - 1) // block),
                              NB - 1)
-            return (bt_r[b, ki], 0, h, 0)
-        return pl.BlockSpec((1, block, 1, dh), imap)
+            return (bt_r[b, ki], h, 0, 0)
+        return pl.BlockSpec((1, 1, block, dh), imap)
 
     kernel = functools.partial(_paged_verify_kernel, scale=scale,
                                block=block, group=group, L=L,
@@ -455,7 +469,7 @@ def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
                            pos: Array, block_tables: Array, *,
                            window: int = 0, blocks_per_step: int = 1,
                            interpret: bool = False) -> Array:
-    """q: (B,H,dh); k_pool,v_pool: (P,block,KV,dh); pos: (B,) int32;
+    """q: (B,H,dh); k_pool,v_pool: (P,KV,block,dh); pos: (B,) int32;
     block_tables: (B,NB) int32 → (B,H,dh).
 
     Grid = (batch, kv_heads, ⌈NB / blocks_per_step⌉). ``pos`` and the
@@ -472,7 +486,7 @@ def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
     stays live once wrapped, so only the NB bound is clamped).
     """
     B, H, dh = q.shape
-    P, block, KV = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    P, KV, block = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
     NB = block_tables.shape[1]
     assert H % KV == 0
     group = H // KV
@@ -489,15 +503,15 @@ def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
                 # clamped to NB - 1, so bt_r[b, ki] stays in-table
                 ki = jnp.minimum(jnp.minimum(kc * bps + j,
                                              pos_r[b] // block), NB - 1)
-                return (bt_r[b, ki], 0, h, 0)
+                return (bt_r[b, ki], h, 0, 0)
         else:
             def imap(b, h, kc, pos_r, bt_r):
                 # repro: bounds bt_r holds pool block ids < P (the
                 # pool's leading dim) — allocator invariant; ki is
                 # clamped to NB - 1, so bt_r[b, ki] stays in-table
                 ki = jnp.minimum(kc * bps + j, NB - 1)
-                return (bt_r[b, ki], 0, h, 0)
-        return pl.BlockSpec((1, block, 1, dh), imap)
+                return (bt_r[b, ki], h, 0, 0)
+        return pl.BlockSpec((1, 1, block, dh), imap)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                block=block, window=window, s_log=NB * block,

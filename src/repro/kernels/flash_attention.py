@@ -7,6 +7,12 @@ minor-most (sequential on TPU), carrying the online-softmax state
 the sequence allows. GQA: the key/value BlockSpec index map folds each
 query head onto its KV head (h // group) — no materialized repeat.
 
+The kernel runs on head-major operands, (B, H, S, dh) and (B, KV, S, dh):
+a block's last two dims are then (rows, dh), which the TPU tiling accepts
+(a head axis among the last two would give a block of 1 against H). The
+wrapper transposes the (B, S, H, dh) model layout in and out; the row
+log-sum-exp is kept as (B, H, S, 1) inside for the same reason.
+
 VMEM working set per step: q(bq·dh) + k,v(bk·dh) + acc(bq·dh) + scores
 (bq·bk), all f32 in scratch — ≤ ~2.5 MB at bq=bk=256, dh=128, far under
 the ~16 MB/core budget, leaving room for double-buffered pipelines.
@@ -23,6 +29,11 @@ Array = jnp.ndarray
 NEG_INF = -1e30
 
 
+def heads_major(x: Array) -> Array:
+    """(B, S, H, dh) ↔ (B, H, S, dh): the swap is its own inverse."""
+    return jnp.swapaxes(x, 1, 2)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                   acc_scr, *, scale: float, block_q: int, block_k: int,
                   causal: bool, window: int):
@@ -35,9 +46,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)          # (bk, dv)
+    q = q_ref[0, 0].astype(jnp.float32)                # (bq, dh)
+    k = k_ref[0, 0].astype(jnp.float32)                # (bk, dh)
+    v = v_ref[0, 0].astype(jnp.float32)                # (bk, dv)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -65,10 +76,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     @pl.when(ki == nk - 1)
     def _done():
         out = acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
         # log-sum-exp per query row — the residual the backward pass needs
-        lse_ref[0, :, 0] = (m_scr[...] +
-                            jnp.log(jnp.maximum(l_scr[...], 1e-30)))[:, 0]
+        lse_ref[0, 0] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
 
 
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
@@ -98,26 +108,26 @@ def flash_attention_with_lse(q: Array, k: Array, v: Array, *,
 
     kernel = functools.partial(_flash_kernel, scale=scale, block_q=block_q,
                                block_k=block_k, causal=causal, window=window)
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, dh),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b, h, qi, ki, g=group: (b, ki, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda b, h, qi, ki, g=group: (b, ki, h // g, 0)),
+            pl.BlockSpec((1, 1, block_q, dh),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, dh),
+                         lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, dh),
+                         lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, 1, dh),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_q, 1),
-                         lambda b, h, qi, ki: (b, qi, h)),
+            pl.BlockSpec((1, 1, block_q, dh),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((B, S, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, S, dh), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -125,4 +135,5 @@ def flash_attention_with_lse(q: Array, k: Array, v: Array, *,
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(heads_major(q), heads_major(k), heads_major(v))
+    return heads_major(out), jnp.transpose(lse[..., 0], (0, 2, 1))

@@ -1,9 +1,11 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-On CPU (this container) every kernel runs in ``interpret=True`` — the kernel
-body executes in Python per grid step, validating the exact TPU tiling logic
-against the ref.py oracles. On a real TPU backend interpret=False compiles
-to Mosaic.
+The platform picks the mode. On a TPU every kernel compiles to Mosaic. On
+the CPU (the test suite runs with ``JAX_PLATFORMS=cpu``) every kernel runs
+with ``interpret=True``: the body executes per grid step, validating the
+kernel's logic against the ref.py oracles but not the chip's tiling rules
+(tests/test_tpu_compile.py compiles them for a TPU). Any other platform is
+an error rather than a silent fall back to the interpreter.
 """
 from __future__ import annotations
 
@@ -19,7 +21,13 @@ from . import router_scores as _router
 
 
 def _interpret() -> bool:
-    return jax.default_backend() == "cpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"the Pallas kernels compile for a TPU and are interpreted on "
+            f"the CPU; backend {backend!r} is neither — serve with "
+            f"use_kernel=False there")
+    return backend == "cpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))

@@ -58,10 +58,18 @@ def decode_attention_ref(q: Array, k: Array, v: Array, pos: Array, *,
     return jnp.einsum("bhk,bkhd->bhd", w, vf)
 
 
+def gather_pages(pool: Array, table: Array) -> Array:
+    """The logical KV span behind a block table. pool: (P,KV,block,dh),
+    head-major so a kernel's K/V block is (block, dh) in its last two dims;
+    table: (..., NB) → (..., NB·block, KV, dh), the contiguous layout."""
+    g = jnp.swapaxes(pool[table], -3, -2)          # (..., NB, block, KV, dh)
+    return g.reshape(*table.shape[:-1], -1, *g.shape[-2:])
+
+
 def paged_decode_attention_ref(q: Array, k_pool: Array, v_pool: Array,
                                pos: Array, block_tables: Array, *,
                                window: int = 0) -> Array:
-    """q: (B,H,dh); k_pool,v_pool: (P,block,KV,dh); pos: (B,);
+    """q: (B,H,dh); k_pool,v_pool: (P,KV,block,dh); pos: (B,);
     block_tables: (B,NB) → (B,H,dh).
 
     Definitionally: gather each slot's logical KV span out of the block
@@ -69,10 +77,9 @@ def paged_decode_attention_ref(q: Array, k_pool: Array, v_pool: Array,
     cache size is NB·block; ``window > 0`` applies the ring validity rule
     over that span.
     """
-    B = q.shape[0]
-    NB, block = block_tables.shape[1], k_pool.shape[1]
-    k = k_pool[block_tables].reshape(B, NB * block, *k_pool.shape[2:])
-    v = v_pool[block_tables].reshape(B, NB * block, *v_pool.shape[2:])
+    NB, block = block_tables.shape[1], k_pool.shape[2]
+    k = gather_pages(k_pool, block_tables)
+    v = gather_pages(v_pool, block_tables)
     return decode_attention_ref(q, k, v, pos,
                                 window=NB * block if window > 0 else 0)
 
@@ -80,16 +87,15 @@ def paged_decode_attention_ref(q: Array, k_pool: Array, v_pool: Array,
 def chunk_prefill_attention_ref(q: Array, k_pool: Array, v_pool: Array,
                                 start: Array, block_table: Array) -> Array:
     """q: (C,H,dh) chunk queries (row c at absolute position start + c);
-    k_pool,v_pool: (P,block,KV,dh); block_table: (NB,) → (C,H,dh).
+    k_pool,v_pool: (P,KV,block,dh); block_table: (NB,) → (C,H,dh).
 
     Definitionally: gather the request's logical KV span out of the pool,
     then run the contiguous decode oracle treating the chunk rows as a
     batch of single queries at positions start..start+C-1.
     """
     C = q.shape[0]
-    NB, block = block_table.shape[0], k_pool.shape[1]
-    k = k_pool[block_table].reshape(NB * block, *k_pool.shape[2:])
-    v = v_pool[block_table].reshape(NB * block, *v_pool.shape[2:])
+    k = gather_pages(k_pool, block_table)
+    v = gather_pages(v_pool, block_table)
     kb = jnp.broadcast_to(k[None], (C,) + k.shape)
     vb = jnp.broadcast_to(v[None], (C,) + v.shape)
     pos = start + jnp.arange(C)

@@ -1,14 +1,18 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch × input-shape × mesh)
 combination with ShapeDtypeStruct inputs (zero allocation), print
 memory/cost analysis, extract the roofline terms and the collective
 schedule, and verify the decentralized mode's zero-cross-pod property.
 
-The two lines above MUST stay the first statements in this module: jax
-locks the device count at first init, and only the dry-run may see 512
-placeholder devices (smoke tests and benches see the 1 real CPU device).
+The three lines above MUST stay the first statements in this module: jax
+locks the platform and the device count at first init, and only the
+dry-run may see 512 placeholder devices (smoke tests and benches see the
+1 real CPU device). Pinning the CPU keeps the dry-run off an attached
+accelerator: it would otherwise take the chip, and its 256/512-device
+meshes cannot be built there.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3_8b \

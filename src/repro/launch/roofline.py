@@ -22,7 +22,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-# TPU v5e hardware constants (targets; this container is CPU-only)
+# TPU v5e published peaks per chip (Google Cloud documentation, "TPU v5e")
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
 HBM_BW = 819e9               # bytes/s per chip
 ICI_BW = 50e9                # bytes/s per link

@@ -35,6 +35,7 @@ from repro.checkpoint import ckpt
 from repro.configs.base import ARCH_IDS, get_smoke_config
 from repro.core.router import CentroidRouter, RouterConfig
 from repro.data.synthetic import SyntheticConfig, SyntheticMultimodal
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve.api import EngineConfig, QoSConfig, SamplingParams
 from repro.serve.ensemble_engine import DecentralizedServer
@@ -169,6 +170,7 @@ def main() -> None:
     ap.add_argument("--vocab", type=int, default=512)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     centroids, tau, _ = ckpt.load_router(args.run)
     router = CentroidRouter(jnp.asarray(centroids, jnp.float32),

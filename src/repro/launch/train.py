@@ -6,10 +6,9 @@
    checkpoints — zero communication), or the dense baseline on everything;
 4. save per-expert checkpoints + the router.
 
-On this CPU container it runs the reduced (smoke) configs against the
-synthetic clustered corpus end-to-end; on a TPU cluster the same entrypoint
-drives the production mesh (each expert maps to one pod — see
-sharding/rules.py and the dry-run).
+It runs the reduced (smoke) configs against the synthetic clustered corpus
+end to end; the production mesh (each expert maps to one pod — see
+sharding/rules.py) is so far only lowered by the dry-run.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3_8b \
         --mode decentralized --experts 2 --steps 200 --out /tmp/run
@@ -28,6 +27,7 @@ from repro.configs.base import ARCH_IDS, get_smoke_config
 from repro.data.partition import partition_dataset
 from repro.data.pipeline import LoaderConfig, ShardLoader, expert_loaders
 from repro.data.synthetic import SyntheticConfig, SyntheticMultimodal
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import (TrainConfig, init_train_state,
@@ -62,6 +62,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default="/tmp/repro_run")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch).reduced(vocab=args.vocab)
     model = build_model(cfg)

@@ -12,6 +12,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import gather_pages
+
 from .layers import apply_rope, rms_norm
 from .params import ParamSpec
 
@@ -174,7 +176,7 @@ def paged_decode_attention(params, x: Array, cfg,
                            block_tables: Array, *,
                            use_kernel: bool = False, rope: bool = True):
     """One-token decode against a PAGED KV cache. x: (B, 1, D); pool K/V:
-    (P, block, KV, dh) shared block pool; pos: (B,) current positions;
+    (P, KV, block, dh) shared block pool; pos: (B,) current positions;
     block_tables: (B, NB) logical-block → physical-block map per slot.
     Returns (out (B, 1, D), new pool).
 
@@ -187,7 +189,7 @@ def paged_decode_attention(params, x: Array, cfg,
     """
     B = x.shape[0]
     k_pool, v_pool = pool
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     NB = block_tables.shape[1]
     S_log = NB * bs
     pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
@@ -198,8 +200,8 @@ def paged_decode_attention(params, x: Array, cfg,
     r = pos_b % S_log if cfg.sliding_window > 0 else pos_b
     blk = jnp.take_along_axis(block_tables, (r // bs)[:, None], axis=1)[:, 0]
     off = r % bs
-    k_pool = k_pool.at[blk, off].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v_new[:, 0].astype(v_pool.dtype))
+    k_pool = k_pool.at[blk, :, off].set(k_new[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[blk, :, off].set(v_new[:, 0].astype(v_pool.dtype))
     if use_kernel:
         from repro.kernels import ops as kops
         out = kops.paged_decode_attention(q[:, 0], k_pool, v_pool, pos_b,
@@ -207,8 +209,8 @@ def paged_decode_attention(params, x: Array, cfg,
                                           window=cfg.sliding_window)
         out = out[:, None]
     else:
-        kf = k_pool[block_tables].reshape(B, S_log, *k_pool.shape[2:])
-        vf = v_pool[block_tables].reshape(B, S_log, *v_pool.shape[2:])
+        kf = gather_pages(k_pool, block_tables)
+        vf = gather_pages(v_pool, block_tables)
         idx = jnp.arange(S_log)[None, :]
         if cfg.sliding_window > 0:
             valid = (idx <= pos_b[:, None]) | (pos_b[:, None] >= S_log)
@@ -228,7 +230,7 @@ def paged_verify_attention(params, x: Array, cfg,
 
     x: (B, L, D) — row ℓ of slot b is the candidate token sitting at
     absolute position ``pos[b] + ℓ`` (row 0 is the slot's committed next
-    token, rows 1..L-1 are draft tokens); pool K/V: (P, block, KV, dh);
+    token, rows 1..L-1 are draft tokens); pool K/V: (P, KV, block, dh);
     pos: (B,) each slot's current write position; block_tables: (B, NB).
     Returns (out (B, L, D), new pool).
 
@@ -247,7 +249,7 @@ def paged_verify_attention(params, x: Array, cfg,
     """
     B, L, D = x.shape
     k_pool, v_pool = pool
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     NB = block_tables.shape[1]
     S_log = NB * bs
     pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
@@ -259,17 +261,17 @@ def paged_verify_attention(params, x: Array, cfg,
     blk = jnp.where(
         safe, block_tables[rows, jnp.clip(flat_pos // bs, 0, NB - 1)], 0)
     off = jnp.where(safe, flat_pos % bs, 0)
-    k_pool = k_pool.at[blk, off].set(
+    k_pool = k_pool.at[blk, :, off].set(
         k_new.reshape(B * L, *k_new.shape[2:]).astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(
+    v_pool = v_pool.at[blk, :, off].set(
         v_new.reshape(B * L, *v_new.shape[2:]).astype(v_pool.dtype))
     if use_kernel:
         from repro.kernels import ops as kops
         out = kops.paged_verify_attention(q, k_pool, v_pool, pos_b,
                                           block_tables)
     else:
-        kf = k_pool[block_tables].reshape(B, S_log, *k_pool.shape[2:])
-        vf = v_pool[block_tables].reshape(B, S_log, *v_pool.shape[2:])
+        kf = gather_pages(k_pool, block_tables)
+        vf = gather_pages(v_pool, block_tables)
         idx = jnp.arange(S_log)[None, None, :]
         valid = idx <= positions[:, :, None]                # (B, L, S_log)
         out = gqa_sdpa(q, kf, vf, valid, jnp.dtype(cfg.attn_softmax_dtype))
@@ -283,7 +285,7 @@ def chunk_attention(params, x: Array, cfg, pool: Tuple[Array, Array],
     """Chunked-prefill self-attention THROUGH the paged pool.
 
     x: (1, C, D) chunk hidden states whose row c sits at absolute position
-    ``start + c``; pool K/V: (P, block, KV, dh) shared block pool;
+    ``start + c``; pool K/V: (P, KV, block, dh) shared block pool;
     ``length``: () int32 valid rows in this chunk (a final partial chunk is
     right-padded to C); block_table: (NB,) int32 — THIS request's logical →
     physical block map. Returns (out (1, C, D), new pool).
@@ -297,7 +299,7 @@ def chunk_attention(params, x: Array, cfg, pool: Tuple[Array, Array],
     """
     B, C, D = x.shape
     k_pool, v_pool = pool
-    bs = k_pool.shape[1]
+    bs = k_pool.shape[2]
     NB = block_table.shape[0]
     S_log = NB * bs
     offs = jnp.arange(C)
@@ -307,15 +309,15 @@ def chunk_attention(params, x: Array, cfg, pool: Tuple[Array, Array],
     blk = jnp.where(valid,
                     block_table[jnp.clip(pos_c // bs, 0, NB - 1)], 0)
     off = jnp.where(valid, pos_c % bs, 0)
-    k_pool = k_pool.at[blk, off].set(k_new[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, off].set(v_new[0].astype(v_pool.dtype))
+    k_pool = k_pool.at[blk, :, off].set(k_new[0].astype(k_pool.dtype))
+    v_pool = v_pool.at[blk, :, off].set(v_new[0].astype(v_pool.dtype))
     if use_kernel:
         from repro.kernels import ops as kops
         out = kops.chunk_prefill_attention(q[0], k_pool, v_pool, start,
                                            block_table)[None]
     else:
-        kf = k_pool[block_table].reshape(S_log, *k_pool.shape[2:])[None]
-        vf = v_pool[block_table].reshape(S_log, *v_pool.shape[2:])[None]
+        kf = gather_pages(k_pool, block_table)[None]
+        vf = gather_pages(v_pool, block_table)[None]
         mask = (jnp.arange(S_log)[None, :] <= pos_c[:, None])[None]
         out = gqa_sdpa(q, kf, vf, mask, jnp.dtype(cfg.attn_softmax_dtype))
     proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))

@@ -84,9 +84,13 @@ class PagedLayout:
     contiguous layout (e.g. attention KV (L, B, S, KV, dh) → 2); leaves
     that stay on the direct per-slot path (recurrent states, enc-dec
     cross-attention KV) carry ``-1``. In the pool layout a paged leaf's
-    (B, S) pair is replaced by (n_blocks, block_size) and addressed through
-    a per-slot block table — so every slot pays only for the blocks it has
-    actually written instead of a full-length cache row.
+    batch axis becomes the physical-block axis (n_blocks) and its sequence
+    axis moves in front of the last (head_dim) axis as the in-block offset:
+    attention KV (L, B, S, KV, dh) pages as (L, n_blocks, KV, block_size,
+    dh), head-major, so an attention kernel's K/V block is (block_size, dh)
+    in its last two dims. Blocks are addressed through a per-slot block
+    table — so every slot pays only for the blocks it has actually written
+    instead of a full-length cache row.
     """
     block_size: int
     seq_axes: Any
@@ -164,10 +168,10 @@ class CacheSpec:
                 if s_ax < 0:
                     return jax.lax.dynamic_update_slice_in_dim(
                         full, row.astype(full.dtype), slot, axis=b_ax)
-                # pool leaf: contiguous row is (..., 1, S, ...) with the
-                # batch extent-1 at b_ax and the sequence at
-                # s_ax == b_ax + 1; the pool is (..., P, bs, ...) at the
-                # same axis positions.
+                # pool leaf: contiguous row is (..., 1, S, ..., dh) with
+                # the batch extent-1 at b_ax and the sequence at
+                # s_ax == b_ax + 1; the pool is (..., P, ..., bs, dh)
+                # (see PagedLayout).
                 assert s_ax == b_ax + 1, (b_ax, s_ax)
                 row = jnp.squeeze(row, axis=b_ax)      # seq now at b_ax
                 take = min(nb * bs, row.shape[b_ax])
@@ -178,6 +182,7 @@ class CacheSpec:
                     row = jnp.pad(row, pad)
                 row = row.reshape(row.shape[:b_ax] + (nb, bs)
                                   + row.shape[b_ax + 1:])
+                row = jnp.moveaxis(row, b_ax + 1, -2)  # offset before dh
                 idx = (slice(None),) * b_ax + (blocks,)
                 return full.at[idx].set(row.astype(full.dtype))
 
@@ -237,7 +242,7 @@ class CacheSpec:
             if s_ax < 0:
                 return jax.lax.dynamic_slice_in_dim(full, slot, 1,
                                                     axis=b_ax)
-            # pool leaf: (..., P, bs, ...) with the block axis at b_ax
+            # pool leaf: the physical-block axis sits at b_ax
             idx = (slice(None),) * b_ax + (blocks,)
             return full[idx]
 
@@ -582,17 +587,18 @@ class Model:
 
     def _paged_cache_struct(self, n_slots: int, n_blocks: int,
                             block_size: int, cache_len: int, as_shape: bool):
-        """Paged decode cache: pool leaves replace their (B, S) pair with
-        (n_blocks, block_size) — one shared pool addressed through per-slot
-        block tables; direct leaves keep their n_slots rows."""
+        """Paged decode cache: pool leaves turn their batch axis into
+        n_blocks and move the in-block offset (block_size) in front of the
+        head dim (``PagedLayout``) — one shared pool addressed through
+        per-slot block tables; direct leaves keep their n_slots rows."""
         base = self._cache_struct(n_slots, cache_len, as_shape=True)
         spec = self.cache_spec(block_size)
 
         def one(s, b_ax, s_ax):
             if s_ax < 0:
                 return s
-            shape = s.shape[:b_ax] + (n_blocks, block_size) \
-                + s.shape[s_ax + 1:]
+            shape = s.shape[:b_ax] + (n_blocks,) + s.shape[s_ax + 1:-1] \
+                + (block_size,) + s.shape[-1:]
             return jax.ShapeDtypeStruct(shape, s.dtype)
 
         shapes = jax.tree.map(one, base, spec.batch_axes,

@@ -211,7 +211,7 @@ def paged_pool_pspec_tree(paged_cache_shapes, rules, mesh: Mesh, seq_axes):
     """Shardings for the PAGED decode cache. ``seq_axes`` is the
     ``CacheSpec.paged.seq_axes`` pytree: leaves marked ``-1`` are direct
     per-slot rows and keep their contiguous placement; pool leaves
-    (scan, P, block, KV, dh) shard the *physical block* axis over the
+    (scan, P, KV, block, dh) shard the *physical block* axis over the
     kv-cache batch axes — blocks, not slots, are the unit of placement, so
     the pool scales with device count while the per-slot block table stays
     replicated host state — and kv-heads over ``model`` when divisible
@@ -233,9 +233,9 @@ def paged_pool_pspec_tree(paged_cache_shapes, rules, mesh: Mesh, seq_axes):
         if shape[pool_ax] % extent == 0 and shape[pool_ax] > 1:
             spec[pool_ax] = b_axes
         if ndim == 5:
-            kv = shape[-2]
+            kv = shape[-3]
             if kv % mesh.shape["model"] == 0 and kv > 1:
-                spec[-2] = "model"
+                spec[-3] = "model"
         return NamedSharding(mesh, P(*spec))
 
     return jax.tree.map(one, paged_cache_shapes, seq_axes)

@@ -50,8 +50,8 @@ def test_chunk_prefill_kernel(C, NB, block, H, KV, dh, start, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     P = NB + 3                            # pool bigger than needed
     q = rand(ks[0], (C, H, dh), dtype)
-    kp = rand(ks[1], (P, block, KV, dh), dtype)
-    vp = rand(ks[2], (P, block, KV, dh), dtype)
+    kp = rand(ks[1], (P, KV, block, dh), dtype)
+    vp = rand(ks[2], (P, KV, block, dh), dtype)
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:NB], jnp.int32)
     out = chunk_prefill_attention(q, kp, vp, jnp.int32(start), bt,
@@ -72,8 +72,8 @@ def test_chunk_prefill_kernel_blocks_per_step(block, NB, start, bps):
     C, H, KV, dh = 5, 8, 4, 32
     P = NB + 2
     q = rand(ks[0], (C, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, block, KV, dh), jnp.float32)
-    vp = rand(ks[2], (P, block, KV, dh), jnp.float32)
+    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
     rng = np.random.default_rng(2)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:NB], jnp.int32)
     base = chunk_prefill_attention(q, kp, vp, jnp.int32(start), bt,
@@ -93,8 +93,8 @@ def test_chunk_prefill_ref_row0_is_decode_ref():
     NB, block, H, KV, dh = 4, 8, 4, 2, 32
     P = NB + 1
     q = rand(ks[0], (1, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, block, KV, dh), jnp.float32)
-    vp = rand(ks[2], (P, block, KV, dh), jnp.float32)
+    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
     bt = jnp.arange(1, NB + 1, dtype=jnp.int32)
     start = jnp.int32(13)
     got = ref.chunk_prefill_attention_ref(q, kp, vp, start, bt)

@@ -199,3 +199,17 @@ def test_model_with_kernels_matches_jnp(arch):
     k_logits = model.forward(params, batch, use_kernel=True)
     np.testing.assert_allclose(np.asarray(k_logits), np.asarray(ref_logits),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_kernel_mode_follows_platform(backend, interpret, monkeypatch):
+    """Kernels are interpreted only on the CPU and compiled on a TPU; any
+    other platform is refused, never silently interpreted."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="neither"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret

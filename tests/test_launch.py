@@ -111,3 +111,41 @@ def test_moe_active_params():
     # 128 experts, top-8 → active well under total, above dense part
     assert active < 0.25 * total
     assert active > 0.02 * total
+
+
+def test_dryrun_pins_cpu_platform(monkeypatch):
+    """The dry-run must never take an attached accelerator: its import
+    pins the CPU next to the 512 placeholder devices, whatever platform
+    the environment asked for."""
+    import importlib
+    import os
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.setenv("XLA_FLAGS", "")
+    importlib.reload(dryrun)
+    assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert "device_count=512" in os.environ["XLA_FLAGS"]
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_dir(env_dir, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and nothing is
+    set in code; otherwise the cache is the fixed in-checkout directory."""
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(compile_cache, "DEFAULT_DIR", tmp_path / "fixed")
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    else:
+        monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path / env_dir))
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            assert got == str(tmp_path / "fixed")
+            assert (tmp_path / "fixed").is_dir()
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == prev
+            assert not (tmp_path / "fixed").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -50,8 +50,8 @@ def test_paged_decode_kernel(B, NB, block, H, KV, dh, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     P = B * NB + 3                        # pool bigger than needed
     q = rand(ks[0], (B, H, dh), dtype)
-    kp = rand(ks[1], (P, block, KV, dh), dtype)
-    vp = rand(ks[2], (P, block, KV, dh), dtype)
+    kp = rand(ks[1], (P, KV, block, dh), dtype)
+    vp = rand(ks[2], (P, KV, block, dh), dtype)
     rng = np.random.default_rng(0)
     # distinct physical blocks per slot; block 0 reserved (scratch)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
@@ -70,8 +70,8 @@ def test_paged_decode_kernel_ring(pos_vals):
     B, NB, block, H, KV, dh = 2, 4, 16, 4, 2, 64
     P = B * NB + 1
     q = rand(ks[0], (B, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, block, KV, dh), jnp.float32)
-    vp = rand(ks[2], (P, block, KV, dh), jnp.float32)
+    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
     rng = np.random.default_rng(1)
     bt = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(B, NB),
                      jnp.int32)
@@ -96,8 +96,8 @@ def test_paged_decode_kernel_blocks_per_step(block, NB, bps):
     B, H, KV, dh = 3, 8, 4, 32
     P = B * NB + 2
     q = rand(ks[0], (B, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, block, KV, dh), jnp.float32)
-    vp = rand(ks[2], (P, block, KV, dh), jnp.float32)
+    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
     rng = np.random.default_rng(3)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
                      .reshape(B, NB), jnp.int32)
@@ -128,8 +128,8 @@ def test_paged_ref_equals_contiguous_gather():
     k = rand(ks[1], (B, NB * block, KV, dh), jnp.float32)
     v = rand(ks[2], (B, NB * block, KV, dh), jnp.float32)
     pos = jax.random.randint(ks[3], (B,), 0, NB * block)
-    kp = k.reshape(B * NB, block, KV, dh)
-    vp = v.reshape(B * NB, block, KV, dh)
+    kp = jnp.swapaxes(k.reshape(B * NB, block, KV, dh), 1, 2)
+    vp = jnp.swapaxes(v.reshape(B * NB, block, KV, dh), 1, 2)
     bt = jnp.arange(B * NB, dtype=jnp.int32).reshape(B, NB)
     got = ref.paged_decode_attention_ref(q, kp, vp, pos, bt)
     want = ref.decode_attention_ref(q, k, v, pos)
@@ -415,7 +415,7 @@ def test_paged_pool_pspec_layout(arch):
     """Pool leaves shard the physical-block axis over the kv-cache batch
     axes and kv-heads over model; direct leaves keep their contiguous
     placement; the stacked variant carries ``dexpert`` (pod) at axis 1."""
-    from jax.sharding import Mesh
+    from jax.sharding import Mesh, PartitionSpec
     from repro.sharding.rules import (cache_pspec_tree, logical_rules,
                                       paged_pool_pspec_tree,
                                       stacked_cache_pspec_tree)
@@ -434,10 +434,11 @@ def test_paged_pool_pspec_layout(arch):
             want = tuple(plain_ns.spec)
             want += (None,) * (len(leaf.shape) - len(want))
             assert pspec == want, (leaf.shape, pspec, want)
-        else:              # pool leaf (scan, P, block, KV, dh)
-            assert pspec[s_ax - 1] == rules["kv_cache_batch"], \
-                (leaf.shape, pspec)
-            assert pspec[s_ax] is None          # block interior never cut
+        else:              # pool leaf (scan, P, KV, block, dh)
+            # PartitionSpec normalizes a one-axis tuple to its axis name
+            want_pool = PartitionSpec(rules["kv_cache_batch"])[0]
+            assert pspec[s_ax - 1] == want_pool, (leaf.shape, pspec)
+            assert pspec[-2] is None            # block interior never cut
 
     jax.tree.map(check, specs, shapes, spec.paged.seq_axes, plain)
 

@@ -355,8 +355,8 @@ def test_paged_verify_kernel_matches_decode_ref(B, NB, block, H, KV, dh,
     P = B * NB + 3
     dt = jnp.float32
     q = jax.random.normal(ks[0], (B, L, H, dh), dt)
-    kp = jax.random.normal(ks[1], (P, block, KV, dh), dt)
-    vp = jax.random.normal(ks[2], (P, block, KV, dh), dt)
+    kp = jax.random.normal(ks[1], (P, KV, block, dh), dt)
+    vp = jax.random.normal(ks[2], (P, KV, block, dh), dt)
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
                      .reshape(B, NB), jnp.int32)
