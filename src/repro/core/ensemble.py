@@ -36,14 +36,16 @@ def mix_expert_logits(expert_logits: Array, weights: Array,
 
     expert_logits: (K, ..., V); weights: (..., K) (already top-k filtered,
     rows summing to 1). Returns probabilities (..., V) — the exact Eq. 27
-    recomposition (probability space, not logit averaging).
+    recomposition (probability space, not logit averaging). Named scope
+    ``mix``.
     """
-    probs = jax.nn.softmax(expert_logits, axis=-1)          # (K, ..., V)
-    w = jnp.moveaxis(weights, -1, 0)                        # (K, ...)
-    mixed = mix_expert_distributions(probs, w)
-    if log_space:
-        return jnp.log(jnp.maximum(mixed, PROB_FLOOR))
-    return mixed
+    with jax.named_scope("mix"):
+        probs = jax.nn.softmax(expert_logits, axis=-1)      # (K, ..., V)
+        w = jnp.moveaxis(weights, -1, 0)                    # (K, ...)
+        mixed = mix_expert_distributions(probs, w)
+        if log_space:
+            return jnp.log(jnp.maximum(mixed, PROB_FLOOR))
+        return mixed
 
 
 @dataclass
@@ -120,15 +122,17 @@ def make_stacked_serving(model, expert_params, cache_len: int, *,
     (``DecentralizedServer``, ``MixtureSlotServer``, serve_bench): experts
     stacked in the decode layout plus jitted whole-ensemble steps.
 
-    Returns ``(stacked, param_axes, prefill_all, mix_decode)`` where
+    Returns ``(stacked, param_axes, mixture_prefill, mixture_decode_probs)``
+    where
 
-    * ``prefill_all(stacked, batch)`` → ``(logits (K, B, S, V), caches)``
-    * ``mix_decode(stacked, caches, tok, pos, weights)`` →
+    * ``mixture_prefill(stacked, batch)`` → ``(logits (K, B, S, V),
+      caches)``
+    * ``mixture_decode_probs(stacked, caches, tok, pos, weights)`` →
       ``(Eq. 27 mixed probabilities (B, V), new caches)`` — ONE vmapped
       ``decode_step`` over the K dim with the mixing fused into the jit.
 
     With ``paged`` the caches are the block-pool layout (pool leaves carry
-    the K dim at axis 1, exactly like the direct leaves) and ``mix_decode``
+    the K dim at axis 1, exactly like the direct leaves) and the decode
     takes the per-slot block tables as a trailing argument, shared across
     all K experts (``in_axes=None`` under the vmap).
     """
@@ -137,14 +141,15 @@ def make_stacked_serving(model, expert_params, cache_len: int, *,
     # caches share it): every leaf carries K at axis 1, after its scan dim
     cache_axes = stacked_cache_axes(model.cache_shapes(1, cache_len))
 
-    def prefill_all(stacked_p, batch):
+    def mixture_prefill(stacked_p, batch):
         return jax.vmap(
             lambda p: model.prefill(p, batch, cache_len,
                                     use_kernel=use_kernel),
             in_axes=(param_axes,), out_axes=(0, cache_axes))(stacked_p)
 
     if paged:
-        def mix_decode(stacked_p, caches, tok, pos, weights, block_tables):
+        def mixture_decode_probs(stacked_p, caches, tok, pos, weights,
+                                 block_tables):
             logits, caches = jax.vmap(
                 lambda p, c: model.decode_step_paged(
                     p, c, tok, pos, block_tables, use_kernel=use_kernel),
@@ -152,7 +157,7 @@ def make_stacked_serving(model, expert_params, cache_len: int, *,
                 out_axes=(0, cache_axes))(stacked_p, caches)  # (K, B, V)
             return mix_expert_logits(logits, weights), caches
     else:
-        def mix_decode(stacked_p, caches, tok, pos, weights):
+        def mixture_decode_probs(stacked_p, caches, tok, pos, weights):
             logits, caches = jax.vmap(
                 lambda p, c: model.decode_step(p, c, tok, pos,
                                                use_kernel=use_kernel),
@@ -160,7 +165,8 @@ def make_stacked_serving(model, expert_params, cache_len: int, *,
                 out_axes=(0, cache_axes))(stacked_p, caches)  # (K, B, V)
             return mix_expert_logits(logits, weights), caches
 
-    return stacked, param_axes, jax.jit(prefill_all), jax.jit(mix_decode)
+    return (stacked, param_axes, jax.jit(mixture_prefill),
+            jax.jit(mixture_decode_probs))
 
 
 def make_stacked_chunk_fns(model, stacked, param_axes, cache_len: int,
@@ -168,28 +174,29 @@ def make_stacked_chunk_fns(model, stacked, param_axes, cache_len: int,
     """Chunked-prefill companions to ``make_stacked_serving`` for the
     stacked-expert mixture core.
 
-    Returns ``(prep_all, chunk_all)``:
+    Returns ``(mixture_prep, mixture_chunk_probs)``:
 
-    * ``prep_all(stacked, batch)`` → (embedded prompt (K, 1, W, D) — every
-      expert owns its embedding table; admission slices off any cached
+    * ``mixture_prep(stacked, batch)`` → (embedded prompt (K, 1, W, D) —
+      every expert owns its embedding table; admission slices off any cached
       prefix and pre-splits the suffix into per-chunk tensors, keeping the
       chunk step dispatch-free — per-expert chunk carries with the K dim
       at axis 1 of every leaf, the same slot the stacked cache keeps it
       in, so ``CacheSpec.shifted(1).insert_direct`` splices the finished
       carry without a transpose);
-    * ``chunk_all(stacked, caches, carry, xc, start, length, block_table,
-      weights)`` → (Eq. 27 mixed next-token probs (1, V) at the chunk's
-      last valid position, new carry, new caches) — ONE vmapped
+    * ``mixture_chunk_probs(stacked, caches, carry, xc, start, length,
+      block_table, weights)`` → (Eq. 27 mixed next-token probs (1, V) at
+      the chunk's last valid position, new carry, new caches) — ONE vmapped
       ``prefill_chunk`` over the K dim; the block table is shared by all K
       experts (``in_axes=None``), exactly like the paged decode path.
 
-    ``chunk_all`` is returned un-jitted so the mixture server can fuse it
-    with the decode step into a single dispatch; ``prep_all`` is jitted
-    (it runs once per admission, retracing per padded prompt width).
+    ``mixture_chunk_probs`` is returned un-jitted so the mixture server can
+    fuse it with the decode step into a single dispatch; ``mixture_prep``
+    is jitted (it runs once per admission, retracing per padded prompt
+    width).
     """
     cache_axes = stacked_cache_axes(model.cache_shapes(1, cache_len))
 
-    def prep_all(stacked_p, batch):
+    def mixture_prep(stacked_p, batch):
         x = jax.vmap(lambda p: model.embed_prompt(p, batch),
                      in_axes=(param_axes,))(stacked_p)     # (K, 1, W, D)
         carry = jax.vmap(
@@ -197,8 +204,8 @@ def make_stacked_chunk_fns(model, stacked, param_axes, cache_len: int,
             in_axes=(param_axes,), out_axes=1)(stacked_p)
         return x, carry
 
-    def chunk_all(stacked_p, caches, carry, xc, start, length, block_table,
-                  weights):
+    def mixture_chunk_probs(stacked_p, caches, carry, xc, start, length,
+                            block_table, weights):
         logits, carry, caches = jax.vmap(
             lambda p, c, cr, x: model.prefill_chunk(
                 p, c, cr, x, start, length, block_table,
@@ -207,7 +214,7 @@ def make_stacked_chunk_fns(model, stacked, param_axes, cache_len: int,
             out_axes=(0, 1, cache_axes))(stacked_p, caches, carry, xc)
         return mix_expert_logits(logits, weights), carry, caches
 
-    return jax.jit(prep_all), chunk_all
+    return jax.jit(mixture_prep), mixture_chunk_probs
 
 
 def make_stacked_fused(model, param_axes, cache_len: int, *,
@@ -219,19 +226,21 @@ def make_stacked_fused(model, param_axes, cache_len: int, *,
     mixed scores are probabilities) in one jitted dispatch, so a mixture
     decode token costs a single kernel launch like the single-model path.
 
-    Returns ``(step, step_chunk, chunk_only)``:
+    Returns ``(mixture_fused_decode, mixture_fused_decode_chunk,
+    mixture_chunk_only)``, the names the device trace shows them by:
 
-    * ``step(stacked, caches, state)`` → ``(caches, state, next_tok,
-      done)`` — ``state`` is the scheduler's per-slot device-state dict
-      (``state["weights"]`` carries the (n_slots, K) router weights,
-      ``state["tables"]`` the block tables when paged);
-    * ``step_chunk(stacked, caches, state, carry, xc, start, length, cbt,
-      w_row, temp, top_k, seed)`` → additionally consumes one prefill
-      chunk and returns its (fused, device-side) first-token pick;
-    * ``chunk_only(...)`` — the chunk + pick without a decode.
+    * ``mixture_fused_decode(stacked, caches, state)`` → ``(caches, state,
+      next_tok, done)`` — ``state`` is the scheduler's per-slot
+      device-state dict (``state["weights"]`` carries the (n_slots, K)
+      router weights, ``state["tables"]`` the block tables when paged);
+    * ``mixture_fused_decode_chunk(stacked, caches, state, carry, xc,
+      start, length, cbt, w_row, temp, top_k, seed)`` → additionally
+      consumes one prefill chunk and returns its (fused, device-side)
+      first-token pick;
+    * ``mixture_chunk_only(...)`` — the chunk + pick without a decode.
 
-    ``step_chunk``/``chunk_only`` are None without ``chunk_all`` (pass the
-    un-jitted chunk fn from ``make_stacked_chunk_fns``).
+    The last two are None without ``chunk_all`` (pass the un-jitted chunk
+    fn from ``make_stacked_chunk_fns``).
     """
     # function-level import: serve.fused imports PROB_FLOOR from here
     from repro.serve.fused import decode_epilogue, pick_first
@@ -255,17 +264,17 @@ def make_stacked_fused(model, param_axes, cache_len: int, *,
                 out_axes=(0, cache_axes))(stacked_p, caches)
             return mix_expert_logits(logits, st["weights"]), caches
 
-    def step(stacked_p, caches, st):
+    def mixture_fused_decode(stacked_p, caches, st):
         probs, caches = mix(stacked_p, caches, st)
         st, nxt, done = decode_epilogue(probs, st, cache_len=cache_len,
                                         from_probs=True)
         return caches, st, nxt, done
 
     if chunk_all is None:
-        return jax.jit(step), None, None
+        return jax.jit(mixture_fused_decode), None, None
 
-    def step_chunk(stacked_p, caches, st, carry, xc, start, length, cbt,
-                   w_row, temp, top_k, seed):
+    def mixture_fused_decode_chunk(stacked_p, caches, st, carry, xc, start,
+                                   length, cbt, w_row, temp, top_k, seed):
         probs, caches = mix(stacked_p, caches, st)
         c_probs, carry, caches = chunk_all(stacked_p, caches, carry, xc,
                                            start, length, cbt, w_row)
@@ -274,14 +283,15 @@ def make_stacked_fused(model, param_axes, cache_len: int, *,
         first = pick_first(c_probs, temp, top_k, seed, from_probs=True)
         return caches, st, nxt, done, first, carry
 
-    def chunk_only(stacked_p, caches, carry, xc, start, length, cbt,
-                   w_row, temp, top_k, seed):
+    def mixture_chunk_only(stacked_p, caches, carry, xc, start, length, cbt,
+                           w_row, temp, top_k, seed):
         c_probs, carry, caches = chunk_all(stacked_p, caches, carry, xc,
                                            start, length, cbt, w_row)
         first = pick_first(c_probs, temp, top_k, seed, from_probs=True)
         return first, carry, caches
 
-    return jax.jit(step), jax.jit(step_chunk), jax.jit(chunk_only)
+    return (jax.jit(mixture_fused_decode),
+            jax.jit(mixture_fused_decode_chunk), jax.jit(mixture_chunk_only))
 
 
 def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
@@ -315,7 +325,7 @@ def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
     from repro.serve.fused import verify_epilogue
     cache_axes = stacked_cache_axes(model.cache_shapes(1, cache_len))
 
-    def verify_core(stacked_p, caches, st, drafts):
+    def mixture_fused_verify(stacked_p, caches, st, drafts):
         tokens = jnp.concatenate([st["tok"][:, None], drafts], axis=1)
         logits, caches = jax.vmap(
             lambda p, c: model.verify_step_paged(
@@ -329,9 +339,9 @@ def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
         return caches, st, toks, n_emit, done
 
     if not expert_draft:
-        return jax.jit(verify_core)
+        return jax.jit(mixture_fused_verify)
 
-    def verify(stacked_p, caches, st):
+    def mixture_fused_verify_self_draft(stacked_p, caches, st):
         draft_p = jax.tree.map(lambda leaf, ax: jnp.take(leaf, 0, axis=ax),
                                stacked_p, param_axes)
         draft_c = jax.tree.map(lambda leaf, ax: jnp.take(leaf, 0, axis=ax),
@@ -345,9 +355,9 @@ def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             drafts.append(tok)
         drafts = jnp.stack(drafts, axis=1)               # (B, L-1)
-        return verify_core(stacked_p, caches, st, drafts)
+        return mixture_fused_verify(stacked_p, caches, st, drafts)
 
-    return jax.jit(verify)
+    return jax.jit(mixture_fused_verify_self_draft)
 
 
 def select_expert_params(stacked_params, expert_idx: Array):

@@ -132,42 +132,47 @@ def decode_attention(params, x: Array, cfg, cache: Tuple[Array, Array],
     B, _, D = x.shape
     k_cache, v_cache = cache
     S_cache = k_cache.shape[1]
-    pos = jnp.asarray(pos)
-    pos_b = jnp.broadcast_to(pos, (B,))
-    q, k_new, v_new = _qkv(params, x, cfg, pos_b[:, None], rope=rope)
-    if pos.ndim == 0:
-        # §Perf H1: scalar position (the serve_step case) — in-place
-        # dynamic_update_slice touches ONE cache slot instead of the
-        # masked-rewrite of the whole cache (which forced SPMD to fully
-        # rematerialize/replicate the cache every step).
-        slot = pos % S_cache if cfg.sliding_window > 0 else pos
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k_new.astype(k_cache.dtype), (0, slot, 0, 0))
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v_new.astype(v_cache.dtype), (0, slot, 0, 0))
-    else:
-        slot = pos_b % S_cache if cfg.sliding_window > 0 else pos_b
-        oh = jax.nn.one_hot(slot, S_cache, dtype=k_cache.dtype)  # (B, S)
-        k_cache = k_cache * (1 - oh)[:, :, None, None] + \
-            oh[:, :, None, None] * k_new.astype(k_cache.dtype)
-        v_cache = v_cache * (1 - oh)[:, :, None, None] + \
-            oh[:, :, None, None] * v_new.astype(v_cache.dtype)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        out = kops.decode_attention(q[:, 0], k_cache, v_cache,
-                                    pos_b, window=cfg.sliding_window)
-        out = out[:, None]
-    else:
-        idx = jnp.arange(S_cache)[None, :]
-        if cfg.sliding_window > 0:
-            # ring buffer: every slot is valid once pos >= S_cache; before
-            # wrapping only slots ≤ pos have been written.
-            valid = (idx <= pos_b[:, None]) | (pos_b[:, None] >= S_cache)
+    with jax.named_scope("attn.qkv"):
+        pos = jnp.asarray(pos)
+        pos_b = jnp.broadcast_to(pos, (B,))
+        q, k_new, v_new = _qkv(params, x, cfg, pos_b[:, None], rope=rope)
+    with jax.named_scope("attn.kv_write"):
+        if pos.ndim == 0:
+            # §Perf H1: scalar position (the serve_step case) — in-place
+            # dynamic_update_slice touches ONE cache slot instead of the
+            # masked-rewrite of the whole cache (which forced SPMD to
+            # fully rematerialize/replicate the cache every step).
+            slot = pos % S_cache if cfg.sliding_window > 0 else pos
+            k_cache = jax.lax.dynamic_update_slice(
+                k_cache, k_new.astype(k_cache.dtype), (0, slot, 0, 0))
+            v_cache = jax.lax.dynamic_update_slice(
+                v_cache, v_new.astype(v_cache.dtype), (0, slot, 0, 0))
         else:
-            valid = idx <= pos_b[:, None]
-        mask = valid[:, None, :]              # (B, 1, S_cache)
-        out = gqa_sdpa(q, k_cache, v_cache, mask, jnp.dtype(cfg.attn_softmax_dtype))
-    proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
+            slot = pos_b % S_cache if cfg.sliding_window > 0 else pos_b
+            oh = jax.nn.one_hot(slot, S_cache, dtype=k_cache.dtype)  # (B, S)
+            k_cache = k_cache * (1 - oh)[:, :, None, None] + \
+                oh[:, :, None, None] * k_new.astype(k_cache.dtype)
+            v_cache = v_cache * (1 - oh)[:, :, None, None] + \
+                oh[:, :, None, None] * v_new.astype(v_cache.dtype)
+    with jax.named_scope("attn.kernel"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            out = kops.decode_attention(q[:, 0], k_cache, v_cache,
+                                        pos_b, window=cfg.sliding_window)
+            out = out[:, None]
+        else:
+            idx = jnp.arange(S_cache)[None, :]
+            if cfg.sliding_window > 0:
+                # ring buffer: every slot is valid once pos >= S_cache;
+                # before wrapping only slots ≤ pos have been written.
+                valid = (idx <= pos_b[:, None]) | (pos_b[:, None] >= S_cache)
+            else:
+                valid = idx <= pos_b[:, None]
+            mask = valid[:, None, :]              # (B, 1, S_cache)
+            out = gqa_sdpa(q, k_cache, v_cache, mask,
+                           jnp.dtype(cfg.attn_softmax_dtype))
+    with jax.named_scope("attn.out"):
+        proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return proj, (k_cache, v_cache)
 
 
@@ -192,33 +197,38 @@ def paged_decode_attention(params, x: Array, cfg,
     bs = k_pool.shape[2]
     NB = block_tables.shape[1]
     S_log = NB * bs
-    pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
-    q, k_new, v_new = _qkv(params, x, cfg, pos_b[:, None], rope=rope)
+    with jax.named_scope("attn.qkv"):
+        pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
+        q, k_new, v_new = _qkv(params, x, cfg, pos_b[:, None], rope=rope)
     # scatter the new token's K/V into each slot's current block — physical
     # blocks are uniquely owned, so the batched scatter never collides
     # (inactive slots all write block 0 offset 0, the scratch block).
-    r = pos_b % S_log if cfg.sliding_window > 0 else pos_b
-    blk = jnp.take_along_axis(block_tables, (r // bs)[:, None], axis=1)[:, 0]
-    off = r % bs
-    k_pool = k_pool.at[blk, :, off].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, :, off].set(v_new[:, 0].astype(v_pool.dtype))
-    if use_kernel:
-        from repro.kernels import ops as kops
-        out = kops.paged_decode_attention(q[:, 0], k_pool, v_pool, pos_b,
-                                          block_tables,
-                                          window=cfg.sliding_window)
-        out = out[:, None]
-    else:
-        kf = gather_pages(k_pool, block_tables)
-        vf = gather_pages(v_pool, block_tables)
-        idx = jnp.arange(S_log)[None, :]
-        if cfg.sliding_window > 0:
-            valid = (idx <= pos_b[:, None]) | (pos_b[:, None] >= S_log)
+    with jax.named_scope("attn.kv_write"):
+        r = pos_b % S_log if cfg.sliding_window > 0 else pos_b
+        blk = jnp.take_along_axis(block_tables, (r // bs)[:, None],
+                                  axis=1)[:, 0]
+        off = r % bs
+        k_pool = k_pool.at[blk, :, off].set(k_new[:, 0].astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, :, off].set(v_new[:, 0].astype(v_pool.dtype))
+    with jax.named_scope("attn.kernel"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            out = kops.paged_decode_attention(q[:, 0], k_pool, v_pool, pos_b,
+                                              block_tables,
+                                              window=cfg.sliding_window)
+            out = out[:, None]
         else:
-            valid = idx <= pos_b[:, None]
-        out = gqa_sdpa(q, kf, vf, valid[:, None, :],
-                       jnp.dtype(cfg.attn_softmax_dtype))
-    proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
+            kf = gather_pages(k_pool, block_tables)
+            vf = gather_pages(v_pool, block_tables)
+            idx = jnp.arange(S_log)[None, :]
+            if cfg.sliding_window > 0:
+                valid = (idx <= pos_b[:, None]) | (pos_b[:, None] >= S_log)
+            else:
+                valid = idx <= pos_b[:, None]
+            out = gqa_sdpa(q, kf, vf, valid[:, None, :],
+                           jnp.dtype(cfg.attn_softmax_dtype))
+    with jax.named_scope("attn.out"):
+        proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return proj, (k_pool, v_pool)
 
 
@@ -252,30 +262,36 @@ def paged_verify_attention(params, x: Array, cfg,
     bs = k_pool.shape[2]
     NB = block_tables.shape[1]
     S_log = NB * bs
-    pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
-    positions = pos_b[:, None] + jnp.arange(L)[None, :]          # (B, L)
-    q, k_new, v_new = _qkv(params, x, cfg, positions, rope=rope)
-    flat_pos = positions.reshape(-1)                             # (B·L,)
-    rows = jnp.repeat(jnp.arange(B), L)
-    safe = flat_pos < S_log
-    blk = jnp.where(
-        safe, block_tables[rows, jnp.clip(flat_pos // bs, 0, NB - 1)], 0)
-    off = jnp.where(safe, flat_pos % bs, 0)
-    k_pool = k_pool.at[blk, :, off].set(
-        k_new.reshape(B * L, *k_new.shape[2:]).astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, :, off].set(
-        v_new.reshape(B * L, *v_new.shape[2:]).astype(v_pool.dtype))
-    if use_kernel:
-        from repro.kernels import ops as kops
-        out = kops.paged_verify_attention(q, k_pool, v_pool, pos_b,
-                                          block_tables)
-    else:
-        kf = gather_pages(k_pool, block_tables)
-        vf = gather_pages(v_pool, block_tables)
-        idx = jnp.arange(S_log)[None, None, :]
-        valid = idx <= positions[:, :, None]                # (B, L, S_log)
-        out = gqa_sdpa(q, kf, vf, valid, jnp.dtype(cfg.attn_softmax_dtype))
-    proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
+    with jax.named_scope("attn.qkv"):
+        pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
+        positions = pos_b[:, None] + jnp.arange(L)[None, :]      # (B, L)
+        q, k_new, v_new = _qkv(params, x, cfg, positions, rope=rope)
+    with jax.named_scope("attn.kv_write"):
+        flat_pos = positions.reshape(-1)                         # (B·L,)
+        rows = jnp.repeat(jnp.arange(B), L)
+        safe = flat_pos < S_log
+        blk = jnp.where(
+            safe, block_tables[rows, jnp.clip(flat_pos // bs, 0, NB - 1)],
+            0)
+        off = jnp.where(safe, flat_pos % bs, 0)
+        k_pool = k_pool.at[blk, :, off].set(
+            k_new.reshape(B * L, *k_new.shape[2:]).astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, :, off].set(
+            v_new.reshape(B * L, *v_new.shape[2:]).astype(v_pool.dtype))
+    with jax.named_scope("attn.kernel"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            out = kops.paged_verify_attention(q, k_pool, v_pool, pos_b,
+                                              block_tables)
+        else:
+            kf = gather_pages(k_pool, block_tables)
+            vf = gather_pages(v_pool, block_tables)
+            idx = jnp.arange(S_log)[None, None, :]
+            valid = idx <= positions[:, :, None]            # (B, L, S_log)
+            out = gqa_sdpa(q, kf, vf, valid,
+                           jnp.dtype(cfg.attn_softmax_dtype))
+    with jax.named_scope("attn.out"):
+        proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return proj, (k_pool, v_pool)
 
 
@@ -302,25 +318,30 @@ def chunk_attention(params, x: Array, cfg, pool: Tuple[Array, Array],
     bs = k_pool.shape[2]
     NB = block_table.shape[0]
     S_log = NB * bs
-    offs = jnp.arange(C)
-    pos_c = start + offs                                     # (C,)
-    q, k_new, v_new = _qkv(params, x, cfg, pos_c[None, :])
-    valid = offs < length
-    blk = jnp.where(valid,
-                    block_table[jnp.clip(pos_c // bs, 0, NB - 1)], 0)
-    off = jnp.where(valid, pos_c % bs, 0)
-    k_pool = k_pool.at[blk, :, off].set(k_new[0].astype(k_pool.dtype))
-    v_pool = v_pool.at[blk, :, off].set(v_new[0].astype(v_pool.dtype))
-    if use_kernel:
-        from repro.kernels import ops as kops
-        out = kops.chunk_prefill_attention(q[0], k_pool, v_pool, start,
-                                           block_table)[None]
-    else:
-        kf = gather_pages(k_pool, block_table)[None]
-        vf = gather_pages(v_pool, block_table)[None]
-        mask = (jnp.arange(S_log)[None, :] <= pos_c[:, None])[None]
-        out = gqa_sdpa(q, kf, vf, mask, jnp.dtype(cfg.attn_softmax_dtype))
-    proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
+    with jax.named_scope("attn.qkv"):
+        offs = jnp.arange(C)
+        pos_c = start + offs                                 # (C,)
+        q, k_new, v_new = _qkv(params, x, cfg, pos_c[None, :])
+    with jax.named_scope("attn.kv_write"):
+        valid = offs < length
+        blk = jnp.where(valid,
+                        block_table[jnp.clip(pos_c // bs, 0, NB - 1)], 0)
+        off = jnp.where(valid, pos_c % bs, 0)
+        k_pool = k_pool.at[blk, :, off].set(k_new[0].astype(k_pool.dtype))
+        v_pool = v_pool.at[blk, :, off].set(v_new[0].astype(v_pool.dtype))
+    with jax.named_scope("attn.kernel"):
+        if use_kernel:
+            from repro.kernels import ops as kops
+            out = kops.chunk_prefill_attention(q[0], k_pool, v_pool, start,
+                                               block_table)[None]
+        else:
+            kf = gather_pages(k_pool, block_table)[None]
+            vf = gather_pages(v_pool, block_table)[None]
+            mask = (jnp.arange(S_log)[None, :] <= pos_c[:, None])[None]
+            out = gqa_sdpa(q, kf, vf, mask,
+                           jnp.dtype(cfg.attn_softmax_dtype))
+    with jax.named_scope("attn.out"):
+        proj = jnp.einsum("bshk,hkd->bsd", out, params["wo"].astype(x.dtype))
     return proj, (k_pool, v_pool)
 
 

@@ -60,19 +60,23 @@ def _maybe_remat(fn, cfg: ModelConfig):
 def scan_layers(body, carry, xs, cfg: ModelConfig):
     """``jax.lax.scan`` over a stacked layer dim — or, when ``cfg.unroll``
     is set (dry-run depth probes), an unrolled python loop producing
-    straight-line HLO with identical semantics."""
-    if not cfg.unroll:
-        return jax.lax.scan(body, carry, xs)
-    L = jax.tree.leaves(xs)[0].shape[0]
-    ys = []
-    for i in range(L):
-        layer = jax.tree.map(lambda a, i=i: a[i], xs)
-        carry, y = body(carry, layer)
-        ys.append(y)
-    if all(y is None for y in ys):
-        return carry, None
-    stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
-    return carry, stacked
+    straight-line HLO with identical semantics. Named scope ``layers``:
+    the loop's own slicing of ``xs`` (each layer's weights and cache) and
+    writing back of the stacked ``ys`` are attributed to it in a device
+    trace, the body's ops to their inner scopes."""
+    with jax.named_scope("layers"):
+        if not cfg.unroll:
+            return jax.lax.scan(body, carry, xs)
+        L = jax.tree.leaves(xs)[0].shape[0]
+        ys = []
+        for i in range(L):
+            layer = jax.tree.map(lambda a, i=i: a[i], xs)
+            carry, y = body(carry, layer)
+            ys.append(y)
+        if all(y is None for y in ys):
+            return carry, None
+        stacked = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
+        return carry, stacked
 
 
 @dataclass(frozen=True)
@@ -139,12 +143,12 @@ class CacheSpec:
         # one jitted splice for ALL slots (the index is a traced scalar):
         # per-leaf unjitted updates each dispatch separately and copy the
         # whole leaf, which shows up as per-admission latency
-        def f(cache, row_cache, slot):
+        def insert_row(cache, row_cache, slot):
             return jax.tree.map(
                 lambda full, row, ax: jax.lax.dynamic_update_slice_in_dim(
                     full, row.astype(full.dtype), slot, axis=ax),
                 cache, row_cache, self.batch_axes)
-        return jax.jit(f)
+        return jax.jit(insert_row)
 
     def insert_paged(self, cache, row_cache, slot: int, blocks: Array):
         """Splice a single-request contiguous prefill cache into the paged
@@ -161,7 +165,7 @@ class CacheSpec:
 
         # jitted across slots (traced scalar); retraces once per distinct
         # block-count nb — bounded by the slot's table length
-        def f(cache, row_cache, slot, blocks):
+        def insert_paged(cache, row_cache, slot, blocks):
             nb = blocks.shape[0]
 
             def one(full, row, b_ax, s_ax):
@@ -188,7 +192,7 @@ class CacheSpec:
 
             seq = self.paged.seq_axes
             return jax.tree.map(one, cache, row_cache, self.batch_axes, seq)
-        return jax.jit(f)
+        return jax.jit(insert_paged)
 
     def insert_direct(self, cache, carry, slot: int):
         """Write a chunked-prefill carry (single-request DIRECT-leaf decode
@@ -202,7 +206,7 @@ class CacheSpec:
         seq = self.paged.seq_axes if self.paged is not None else \
             jax.tree.map(lambda _: -1, self.batch_axes)
 
-        def f(cache, carry, slot):
+        def insert_direct(cache, carry, slot):
             def one(full, row, ax, s_ax):
                 if s_ax >= 0:
                     return full
@@ -210,7 +214,7 @@ class CacheSpec:
                     full, row.astype(full.dtype), slot, axis=ax)
 
             return jax.tree.map(one, cache, carry, self.batch_axes, seq)
-        return jax.jit(f)
+        return jax.jit(insert_direct)
 
     def take(self, cache, slot: int):
         """Read one slot's cache back out (batch extent 1 preserved)."""
@@ -218,12 +222,12 @@ class CacheSpec:
 
     @cached_property
     def _take_jit(self):
-        def f(cache, slot):
+        def take_row(cache, slot):
             return jax.tree.map(
                 lambda full, ax: jax.lax.dynamic_slice_in_dim(full, slot, 1,
                                                               axis=ax),
                 cache, self.batch_axes)
-        return jax.jit(f)
+        return jax.jit(take_row)
 
     def swap_out(self, cache, slot: int, blocks):
         """Read one slot's paged decode state out for host-side parking
@@ -366,14 +370,40 @@ class Model:
 
     def _embed_inputs(self, params, batch) -> Array:
         cfg = self.cfg
-        x = embed(params["embed"], batch["tokens"], cfg.cdtype)
-        if cfg.family == "vlm":
-            p = params["projector"]
-            patches = batch["patches"].astype(cfg.cdtype)     # (B, Np, Dv)
-            proj = jax.nn.gelu(patches @ p["w1"].astype(cfg.cdtype))
-            proj = proj @ p["w2"].astype(cfg.cdtype)
-            x = jnp.concatenate([proj, x], axis=1)            # image prefix
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], batch["tokens"], cfg.cdtype)
+            if cfg.family == "vlm":
+                p = params["projector"]
+                patches = batch["patches"].astype(cfg.cdtype)  # (B, Np, Dv)
+                proj = jax.nn.gelu(patches @ p["w1"].astype(cfg.cdtype))
+                proj = proj @ p["w2"].astype(cfg.cdtype)
+                x = jnp.concatenate([proj, x], axis=1)         # image prefix
         return x
+
+    def _lm_head(self, params, x: Array) -> Array:
+        """Final norm + unembedding (named scope ``lm_head``)."""
+        cfg = self.cfg
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            return unembed(params["embed"], x, cfg.tie_embeddings, cfg.vocab)
+
+    def _attn_mlp_layer(self, layer, x: Array, attend):
+        """One dense/vlm/moe decoder layer of the serving paths.
+        ``attend(attn_params, normed_x)`` → ``(out, kv)`` is the layer's
+        attention (its projections, cache write and kernel carry their own
+        scopes); the pre-norm is attributed to ``attn.qkv``, the residual
+        to ``attn.out`` and the feed-forward half to ``mlp``."""
+        cfg = self.cfg
+        with jax.named_scope("attn.qkv"):
+            xn = rms_norm(x, layer["ln1"], cfg.norm_eps)
+        a, kv = attend(layer["attn"], xn)
+        with jax.named_scope("attn.out"):
+            h = x + a
+        with jax.named_scope("mlp"):
+            y = rms_norm(h, layer["ln2"], cfg.norm_eps)
+            out = h + (moe_lib.moe_ffn(layer["moe"], y, cfg)
+                       if cfg.family == "moe" else swiglu(layer["ffn"], y))
+        return out, kv
 
     def _encode_audio(self, params, frames: Array) -> Array:
         cfg = self.cfg
@@ -404,8 +434,7 @@ class Model:
         block = self._train_block(use_kernel, enc_out,
                                   params.get("shared_attn"))
         x, _ = scan_layers(_maybe_remat(block, cfg), x, params["blocks"], cfg)
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        return unembed(params["embed"], x, cfg.tie_embeddings, cfg.vocab)
+        return self._lm_head(params, x)
 
     def _train_block(self, use_kernel: bool, enc_out: Optional[Array],
                      shared=None):
@@ -719,8 +748,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.tie_embeddings, cfg.vocab)
+        logits = self._lm_head(params, x)
         return logits, cache
 
     # ------------------------------------------------------------------
@@ -790,15 +818,10 @@ class Model:
         if cfg.family in ("dense", "vlm", "moe"):
             def body(xh, layer_and_pool):
                 layer, pool = layer_and_pool
-                a, pool = attn.chunk_attention(
-                    layer["attn"], rms_norm(xh, layer["ln1"], cfg.norm_eps),
-                    cfg, pool, start, length, block_table,
-                    use_kernel=use_kernel)
-                h = xh + a
-                y = rms_norm(h, layer["ln2"], cfg.norm_eps)
-                out = h + (moe_lib.moe_ffn(layer["moe"], y, cfg)
-                           if cfg.family == "moe" else swiglu(layer["ffn"], y))
-                return out, pool
+                return self._attn_mlp_layer(
+                    layer, xh, lambda p, xn: attn.chunk_attention(
+                        p, xn, cfg, pool, start, length, block_table,
+                        use_kernel=use_kernel))
             x, (ks, vs) = scan_layers(
                 body, x, (params["blocks"], (cache["k"], cache["v"])), cfg)
             new_cache = {"k": ks, "v": vs}
@@ -898,10 +921,11 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        h_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
-        logits = unembed(params["embed"], h_last, cfg.tie_embeddings,
-                         cfg.vocab)
+        with jax.named_scope("lm_head"):
+            x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            h_last = jax.lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)
+            logits = unembed(params["embed"], h_last, cfg.tie_embeddings,
+                             cfg.vocab)
         return logits[:, 0], new_carry, new_cache
 
     def _mamba2_chunk(self, p, x, state, length, use_kernel):
@@ -975,19 +999,15 @@ class Model:
         """tokens: (B,) int32; pos: () int32 current position. Returns
         (logits (B, V), new cache)."""
         cfg = self.cfg
-        x = embed(params["embed"], tokens[:, None], cfg.cdtype)  # (B,1,D)
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens[:, None], cfg.cdtype)  # (B,1,D)
 
         if cfg.family in ("dense", "vlm", "moe"):
             def body(x, layer_and_cache):
-                layer, (k, v) = layer_and_cache
-                a, kv = attn.decode_attention(
-                    layer["attn"], rms_norm(x, layer["ln1"], cfg.norm_eps),
-                    cfg, (k, v), pos, use_kernel=use_kernel)
-                h = x + a
-                y = rms_norm(h, layer["ln2"], cfg.norm_eps)
-                out = h + (moe_lib.moe_ffn(layer["moe"], y, cfg)
-                           if cfg.family == "moe" else swiglu(layer["ffn"], y))
-                return out, kv
+                layer, kv = layer_and_cache
+                return self._attn_mlp_layer(
+                    layer, x, lambda p, xn: attn.decode_attention(
+                        p, xn, cfg, kv, pos, use_kernel=use_kernel))
             x, (ks, vs) = scan_layers(
                 body, x, (params["blocks"], (cache["k"], cache["v"])), cfg)
             new_cache = {"k": ks, "v": vs}
@@ -1058,8 +1078,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.tie_embeddings, cfg.vocab)
+        logits = self._lm_head(params, x)
         return logits[:, 0], new_cache
 
     def decode_step_paged(self, params, cache, tokens: Array, pos: Array,
@@ -1074,19 +1093,16 @@ class Model:
         if cfg.family == "ssm":       # no pageable leaves: direct path
             return self.decode_step(params, cache, tokens, pos,
                                     use_kernel=use_kernel)
-        x = embed(params["embed"], tokens[:, None], cfg.cdtype)  # (B,1,D)
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens[:, None], cfg.cdtype)  # (B,1,D)
 
         if cfg.family in ("dense", "vlm", "moe"):
             def body(x, layer_and_cache):
-                layer, (k, v) = layer_and_cache
-                a, kv = attn.paged_decode_attention(
-                    layer["attn"], rms_norm(x, layer["ln1"], cfg.norm_eps),
-                    cfg, (k, v), pos, block_tables, use_kernel=use_kernel)
-                h = x + a
-                y = rms_norm(h, layer["ln2"], cfg.norm_eps)
-                out = h + (moe_lib.moe_ffn(layer["moe"], y, cfg)
-                           if cfg.family == "moe" else swiglu(layer["ffn"], y))
-                return out, kv
+                layer, kv = layer_and_cache
+                return self._attn_mlp_layer(
+                    layer, x, lambda p, xn: attn.paged_decode_attention(
+                        p, xn, cfg, kv, pos, block_tables,
+                        use_kernel=use_kernel))
             x, (ks, vs) = scan_layers(
                 body, x, (params["blocks"], (cache["k"], cache["v"])), cfg)
             new_cache = {"k": ks, "v": vs}
@@ -1141,8 +1157,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.tie_embeddings, cfg.vocab)
+        logits = self._lm_head(params, x)
         return logits[:, 0], new_cache
 
     def verify_step_paged(self, params, cache, tokens: Array, pos: Array,
@@ -1165,19 +1180,16 @@ class Model:
                 f"family '{cfg.family}' (window={cfg.sliding_window}) "
                 "cannot verify speculative spans — check "
                 "speculative_capable before dispatching")
-        x = embed(params["embed"], tokens, cfg.cdtype)           # (B,L,D)
+        with jax.named_scope("embed"):
+            x = embed(params["embed"], tokens, cfg.cdtype)       # (B,L,D)
 
         if cfg.family in ("dense", "vlm", "moe"):
             def body(x, layer_and_cache):
-                layer, (k, v) = layer_and_cache
-                a, kv = attn.paged_verify_attention(
-                    layer["attn"], rms_norm(x, layer["ln1"], cfg.norm_eps),
-                    cfg, (k, v), pos, block_tables, use_kernel=use_kernel)
-                h = x + a
-                y = rms_norm(h, layer["ln2"], cfg.norm_eps)
-                out = h + (moe_lib.moe_ffn(layer["moe"], y, cfg)
-                           if cfg.family == "moe" else swiglu(layer["ffn"], y))
-                return out, kv
+                layer, kv = layer_and_cache
+                return self._attn_mlp_layer(
+                    layer, x, lambda p, xn: attn.paged_verify_attention(
+                        p, xn, cfg, kv, pos, block_tables,
+                        use_kernel=use_kernel))
             x, (ks, vs) = scan_layers(
                 body, x, (params["blocks"], (cache["k"], cache["v"])), cfg)
             new_cache = {"k": ks, "v": vs}
@@ -1205,8 +1217,7 @@ class Model:
         else:
             raise ValueError(cfg.family)
 
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = unembed(params["embed"], x, cfg.tie_embeddings, cfg.vocab)
+        logits = self._lm_head(params, x)
         return logits, new_cache
 
     def fused_verify_step(self, params, cache, state, drafts: Array, *,

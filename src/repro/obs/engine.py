@@ -1,4 +1,4 @@
-"""Per-engine telemetry bundle: registry handles + trace recorder.
+"""Per-engine telemetry bundle: registry handles + trace recorder + spans.
 
 One :class:`EngineObs` per ``_SlotTable`` (per pod on the decentralized
 server). It owns the engine's private :class:`MetricsRegistry` (labelled
@@ -9,22 +9,29 @@ the step loop does dict-free attribute loads, and holds either a real
 The metrics side is **always on** — plain-Python counter bumps and a few
 ``perf_counter`` stamps per engine step, orders of magnitude below the
 device dispatch they time (the ``serve_obs`` bench gates the full
-trace+metrics overhead at ≤ 1.05×). The trace side is off by default:
-every span site checks ``obs.trace.enabled`` (or uses the no-op emit)
-before doing any per-event work.
+trace+metrics overhead at ≤ 1.05×). The trace side is off by default.
+
+Spans (:meth:`EngineObs.span`) are the one way host code marks a stretch
+of its own work. Tracing on, a span stamps the Chrome ring and also
+enters a host annotation on the profiler's trace, so the program's phases
+lie on the same clock as the device's operations. The annotation factory
+is injected by whoever builds the bundle (the scheduler passes
+``jax.profiler.TraceAnnotation``): this package imports no jax. Tracing
+off, every span is one shared no-op object — no clock read, no
+annotation.
 
 Metric catalog lives in docs/observability.md; names are stable surface.
 """
 from __future__ import annotations
 
 import time
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 from repro.obs import metrics as _m
 from repro.obs.trace import (ADMIT_TID, SLOT_TID0, STEP_TID, NullRecorder,
                              TraceRecorder)
 
-__all__ = ["EngineObs"]
+__all__ = ["EngineObs", "NULL_SPAN"]
 
 # Accept-length histogram: speculative spans commit 1..spec_len tokens
 # per verify step; unit-width buckets make the histogram an exact
@@ -32,6 +39,104 @@ __all__ = ["EngineObs"]
 ACCEPT_LEN_BUCKETS = tuple(float(i) for i in range(1, 17))
 # Per-request accept-rate in [0, 1], tenth-width buckets.
 RATE_BUCKETS = tuple(round(0.1 * i, 1) for i in range(0, 11))
+
+
+class _NullSpan:
+    """The span of an engine that is not tracing: every method is a
+    no-op, and one instance serves every call site."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def note(self, **args) -> None:
+        pass
+
+    def stamp(self, t0: float, t1: float, tid: int) -> None:
+        pass
+
+    def kind(self, kind: str) -> None:
+        pass
+
+    def drop(self) -> None:
+        pass
+
+
+NULL_SPAN = _NullSpan()
+
+
+class _NoAnnotation:
+    """Stands in for the profiler's annotation when none was injected."""
+
+    def __init__(self, name: str, **args) -> None:
+        pass
+
+    def __enter__(self) -> "_NoAnnotation":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+class _Span:
+    """One traced stretch of host work: a profiler annotation entered for
+    its whole extent, and a Chrome ``X`` event written to the ring when
+    it closes. The ring event keeps the name, track and args the site
+    gives, and the span's own ``perf_counter`` stamps unless the site
+    supplies boundary stamps that other spans share (:meth:`stamp`)."""
+
+    __slots__ = ("_obs", "_ann", "name", "tid", "args", "t0", "t1",
+                 "_keep")
+
+    def __init__(self, obs: "EngineObs", name: str, tid: int,
+                 args: dict) -> None:
+        self._obs, self.name, self.tid, self.args = obs, name, tid, args
+        self.t0 = self.t1 = None
+        self._keep = True
+
+    def __enter__(self) -> "_Span":
+        self._ann = self._obs.annotate(self.name, pod=self._obs.pod,
+                                       **self.args)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter() if self.t1 is None else self.t1
+        self._ann.__exit__(*exc)
+        if self._keep:
+            self._obs.trace.complete(self.name, self.t0, t1, self.tid,
+                                     args=self.args or None)
+
+    def note(self, **args) -> None:
+        """Add args learnt inside the span (ring args and annotation
+        metadata alike)."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def stamp(self, t0: float, t1: float, tid: int) -> None:
+        """Write the ring event at these stamps, on this track: for spans
+        whose boundaries other spans share exactly (a request's phases
+        tile its latency), and whose track is known only inside."""
+        self.t0, self.t1, self.tid = t0, t1, tid
+
+    def kind(self, kind: str) -> None:
+        """Name a step by what it dispatched, known only once scheduled:
+        the ring event becomes ``<name>:<kind>``; the annotation, named
+        when it was entered, carries ``kind`` as an arg."""
+        self.name = f"{self.name}:{kind}"
+        self._ann.set_metadata(kind=kind)
+
+    def drop(self) -> None:
+        """Write no ring event (an admission attempt that failed)."""
+        self._keep = False
 
 
 class EngineObs:
@@ -45,11 +150,17 @@ class EngineObs:
     trace_ring: ring capacity when tracing.
     publish: attach this registry to the process-global exposition set
         (``EngineConfig(metrics=True)``).
+    annotate: the profiler's host-annotation factory, called as
+        ``annotate(name, **args)`` and entered for each span's extent
+        while tracing (``jax.profiler.TraceAnnotation``); None writes the
+        Chrome ring only.
     """
 
     def __init__(self, *, pod: int = 0, trace: bool = False,
-                 trace_ring: int = 65536, publish: bool = False) -> None:
+                 trace_ring: int = 65536, publish: bool = False,
+                 annotate: Optional[Callable[..., object]] = None) -> None:
         self.pod = pod
+        self.annotate = annotate if annotate is not None else _NoAnnotation
         self.registry = _m.MetricsRegistry(base_labels={"pod": str(pod)})
         self.trace: NullRecorder = (
             TraceRecorder(capacity=trace_ring, pid=pod) if trace
@@ -76,6 +187,9 @@ class EngineObs:
         self.readback_s = r.histogram(
             "serve_step_device_get_seconds",
             "host time blocked in the one per-step jax.device_get")
+        self.router_s = r.histogram(
+            "serve_router_seconds",
+            "host time of one front-end routing call, readback included")
         self.active_g = r.gauge("serve_active_slots",
                                 "slots holding a live request")
         self.waiting_g = r.gauge("serve_waiting_requests",
@@ -234,20 +348,10 @@ class EngineObs:
     def slot_tid(slot: int) -> int:
         return SLOT_TID0 + slot
 
-    def step_timing(self, kind: str, t0: float, t1: float) -> None:
-        """Record one step's dispatch/readback split (t2 = now).
-
-        ``t0`` → dispatch begins, ``t1`` → dispatch returned (device
-        launch queued), now → ``jax.device_get`` readback done. The
-        histograms always update; the trace gets a nested
-        step ⊃ {dispatch, device_get} span triple on the step track.
-        """
-        t2 = time.perf_counter()
-        self.dispatch_s.observe(t1 - t0)
-        self.readback_s.observe(t2 - t1)
-        tr = self.trace
-        if tr.enabled:
-            tr.complete(f"step:{kind}", t0, t2, STEP_TID)
-            tr.complete("dispatch", t0, t1, STEP_TID)
-            tr.complete("device_get", t1, t2, STEP_TID)
-        return None
+    def span(self, name: str, tid: int = STEP_TID, **args):
+        """A context manager marking host work ``name`` on track ``tid``
+        (``args`` go to the ring event and the annotation, which also
+        carries ``pod``). Tracing off it is :data:`NULL_SPAN`."""
+        if not self.trace.enabled:
+            return NULL_SPAN
+        return _Span(self, name, tid, args)

@@ -21,7 +21,9 @@ Event vocabulary (Chrome trace_event, the subset Perfetto renders)
 ------------------------------------------------------------------
 * ``"X"`` complete spans — ``ts``/``dur`` in integer microseconds. Used
   for everything slot-serial: admission, prefix_match, prefill_chunk[i],
-  prefill/decode phases, and the per-step dispatch/device_get pair.
+  prefill/decode phases, and each pod step with the phases that tile it
+  (written by ``EngineObs.span``, which also hands the host-nested ones
+  to the profiler).
   Same-track "X" spans must nest (contain or be disjoint) — the schema
   test enforces this.
 * ``"b"``/``"e"`` async spans keyed by ``id`` — used for ``queued``,
@@ -42,7 +44,6 @@ object format ``ui.perfetto.dev`` and ``chrome://tracing`` both load.
 from __future__ import annotations
 
 import json
-import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
 
@@ -134,7 +135,6 @@ class TraceRecorder(NullRecorder):
         self.capacity = capacity
         self._ring: "deque[dict]" = deque(maxlen=capacity)
         self._meta: Dict[tuple, dict] = {}
-        self._t0 = time.perf_counter()  # kept for reference; ts are absolute
 
     def _push(self, ev: dict) -> None:
         if len(self._ring) == self.capacity:
@@ -144,8 +144,9 @@ class TraceRecorder(NullRecorder):
     # -- emission ---------------------------------------------------------
     def complete(self, name: str, t0: float, t1: float, tid: int,
                  cat: str = "span", args: Optional[dict] = None) -> None:
-        ev = {"name": name, "cat": cat, "ph": "X", "ts": us(t0),
-              "dur": max(0, us(t1) - us(t0)), "pid": self.pid, "tid": tid}
+        ts = us(t0)
+        ev = {"name": name, "cat": cat, "ph": "X", "ts": ts,
+              "dur": max(0, us(t1) - ts), "pid": self.pid, "tid": tid}
         if args:
             ev["args"] = args
         self._push(ev)

@@ -80,19 +80,20 @@ def _sample_tokens(scores, temps, top_ks, seeds, counts):
     seeds/counts: (B,) uint32/int32 — token ``counts[b]`` of request
     ``seeds[b]`` draws from ``fold_in(PRNGKey(seed), count)``, so a
     request's sampled continuation depends only on (seed, scores), never
-    on slot placement or co-scheduled traffic.
+    on slot placement or co-scheduled traffic. Named scope ``sample``.
     """
-    V = scores.shape[-1]
-    greedy = jnp.argmax(scores, axis=-1).astype(jnp.int32)
-    k = jnp.where(top_ks <= 0, V, jnp.minimum(top_ks, V))
-    srt = jnp.sort(scores, axis=-1)                      # ascending
-    thresh = jnp.take_along_axis(srt, (V - k)[:, None], axis=-1)
-    masked = jnp.where(scores >= thresh, scores, -jnp.inf)
-    scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
-    keys = jax.vmap(lambda s, c: jax.random.fold_in(
-        jax.random.PRNGKey(s), c))(seeds, counts)
-    sampled = jax.vmap(jax.random.categorical)(keys, scaled)
-    return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
+    with jax.named_scope("sample"):
+        V = scores.shape[-1]
+        greedy = jnp.argmax(scores, axis=-1).astype(jnp.int32)
+        k = jnp.where(top_ks <= 0, V, jnp.minimum(top_ks, V))
+        srt = jnp.sort(scores, axis=-1)                  # ascending
+        thresh = jnp.take_along_axis(srt, (V - k)[:, None], axis=-1)
+        masked = jnp.where(scores >= thresh, scores, -jnp.inf)
+        scaled = masked / jnp.maximum(temps, 1e-6)[:, None]
+        keys = jax.vmap(lambda s, c: jax.random.fold_in(
+            jax.random.PRNGKey(s), c))(seeds, counts)
+        sampled = jax.vmap(jax.random.categorical)(keys, scaled)
+        return jnp.where(temps > 0, sampled.astype(jnp.int32), greedy)
 
 
 sample_tokens = jax.jit(_sample_tokens)
@@ -112,19 +113,21 @@ sample_tokens_probs = jax.jit(_sample_tokens_probs)
 #: of ``_SlotTable._next_tokens``. The eager ``jnp.argmax`` it replaces was
 #: an un-fused device dispatch (and implicit sync) per step on the host
 #: side of the legacy epilogue (the PR 6 incident repro-lint now flags).
-argmax_tokens = jax.jit(
-    lambda scores: jnp.argmax(scores, axis=-1).astype(jnp.int32))
+@jax.jit
+def argmax_tokens(scores):
+    return jnp.argmax(scores, axis=-1).astype(jnp.int32)
 
 
 def pick_first(row, temp, top_k, seed, *, from_probs: bool = False):
     """First token from a prefill's last-position scores (``row``: (1, V))
     — count 0 of the request's seeded stream, greedy when ``temp <= 0``.
     Pure (meant to be fused into the prefill/chunk dispatch); returns the
-    (1,) int32 token on device."""
-    if from_probs:
-        row = jnp.log(jnp.maximum(row, PROB_FLOOR))
-    return _sample_tokens(row, temp, top_k, seed,
-                          jnp.zeros((1,), jnp.int32))
+    (1,) int32 token on device. Named scope ``sample``."""
+    with jax.named_scope("sample"):
+        if from_probs:
+            row = jnp.log(jnp.maximum(row, PROB_FLOOR))
+        return _sample_tokens(row, temp, top_k, seed,
+                              jnp.zeros((1,), jnp.int32))
 
 
 def decode_epilogue(scores, state, *, cache_len: int,
@@ -142,30 +145,35 @@ def decode_epilogue(scores, state, *, cache_len: int,
     next step (finished rows parked at tok/pos 0 — the scratch-writing
     idle configuration — and deactivated), the (n_slots,) tokens decoded
     this step (inactive rows keep their input token and must be ignored),
-    and the (n_slots,) ``DONE_REASONS`` bitmap.
+    and the (n_slots,) ``DONE_REASONS`` bitmap. Named scopes: ``sample``
+    for the token pick, ``epilogue`` for the checks and the advance.
     """
-    if from_probs:
-        scores = jnp.log(jnp.maximum(scores, PROB_FLOOR))
-    nxt = _sample_tokens(scores, state["temps"], state["top_ks"],
-                         state["seeds"], state["counts"])
-    active = state["active"]
-    nxt = jnp.where(active, nxt, state["tok"]).astype(jnp.int32)
-    counts = state["counts"] + active.astype(jnp.int32)
-    pos = state["pos"] + active.astype(jnp.int32)
-    # reason precedence mirrors Request.reason_now + _advance exactly:
-    # stop > length > truncated, each gated on the slot being active
-    is_stop = active & jnp.any(nxt[:, None] == state["stop_ids"], axis=-1)
-    is_len = active & (counts >= state["max_new"])
-    is_trunc = active & (pos >= cache_len)
-    done = jnp.where(is_stop, 1,
-                     jnp.where(is_len, 2,
-                               jnp.where(is_trunc, 3, 0))).astype(jnp.int32)
-    fin = done > 0
-    new_state = dict(state,
-                     tok=jnp.where(fin, 0, nxt).astype(jnp.int32),
-                     pos=jnp.where(fin, 0, pos).astype(jnp.int32),
-                     counts=counts,
-                     active=active & ~fin)
+    with jax.named_scope("sample"):
+        if from_probs:
+            scores = jnp.log(jnp.maximum(scores, PROB_FLOOR))
+        nxt = _sample_tokens(scores, state["temps"], state["top_ks"],
+                             state["seeds"], state["counts"])
+    with jax.named_scope("epilogue"):
+        active = state["active"]
+        nxt = jnp.where(active, nxt, state["tok"]).astype(jnp.int32)
+        counts = state["counts"] + active.astype(jnp.int32)
+        pos = state["pos"] + active.astype(jnp.int32)
+        # reason precedence mirrors Request.reason_now + _advance exactly:
+        # stop > length > truncated, each gated on the slot being active
+        is_stop = active & jnp.any(nxt[:, None] == state["stop_ids"],
+                                   axis=-1)
+        is_len = active & (counts >= state["max_new"])
+        is_trunc = active & (pos >= cache_len)
+        done = jnp.where(is_stop, 1,
+                         jnp.where(is_len, 2,
+                                   jnp.where(is_trunc, 3, 0))
+                         ).astype(jnp.int32)
+        fin = done > 0
+        new_state = dict(state,
+                         tok=jnp.where(fin, 0, nxt).astype(jnp.int32),
+                         pos=jnp.where(fin, 0, pos).astype(jnp.int32),
+                         counts=counts,
+                         active=active & ~fin)
     return new_state, nxt, done
 
 
@@ -204,52 +212,54 @@ def verify_epilogue(scores, drafts, state, *, cache_len: int,
     1 for an active slot (offset 0 never needs a draft: all-reject spans
     still make forward progress), at most L — and ``done`` the
     ``DONE_REASONS`` bitmap. One ``device_get`` of the triple is the
-    step's entire host readback.
+    step's entire host readback. Named scopes as ``decode_epilogue``'s.
     """
     B, L, V = scores.shape
-    if from_probs:
-        scores = jnp.log(jnp.maximum(scores, PROB_FLOOR))
-    active = state["active"]
-    offs = jnp.arange(L, dtype=jnp.int32)
-    # the vanilla trajectory's token at each offset: count c0 + j of the
-    # request's seeded stream (greedy rows take the argmax, same as ever)
-    true = jnp.stack(
-        [_sample_tokens(scores[:, j], state["temps"], state["top_ks"],
-                        state["seeds"], state["counts"] + j)
-         for j in range(L)], axis=1)                          # (B, L)
-    if L > 1:
-        match = (drafts == true[:, :L - 1]).astype(jnp.int32)
-        n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)   # (B,)
-    else:
-        n_acc = jnp.zeros((B,), jnp.int32)
-    m_max = n_acc + 1            # accepted drafts + the free bonus token
-    cnt_after = state["counts"][:, None] + 1 + offs[None, :]  # (B, L)
-    pos_after = state["pos"][:, None] + 1 + offs[None, :]
-    is_stop = jnp.any(true[:, :, None] == state["stop_ids"][:, None, :],
-                      axis=-1)
-    is_len = cnt_after >= state["max_new"][:, None]
-    is_trunc = pos_after >= cache_len
-    halt = is_stop | is_len | is_trunc                        # (B, L)
-    first_halt = jnp.where(jnp.any(halt, axis=1),
-                           jnp.argmax(halt, axis=1), L).astype(jnp.int32)
-    m = jnp.minimum(m_max, first_halt + 1)
-    m = jnp.where(active, m, 0).astype(jnp.int32)
-    halted = active & (first_halt < m_max)
-    code = jnp.where(is_stop, 1, jnp.where(is_len, 2, 3))
-    h = jnp.clip(first_halt, 0, L - 1)
-    done = jnp.where(halted,
-                     jnp.take_along_axis(code, h[:, None], axis=1)[:, 0],
-                     0).astype(jnp.int32)
-    fin = done > 0
-    counts = state["counts"] + m
-    pos = state["pos"] + m
-    last = jnp.take_along_axis(
-        true, jnp.maximum(m - 1, 0)[:, None], axis=1)[:, 0]
-    nxt = jnp.where(active, last, state["tok"]).astype(jnp.int32)
-    new_state = dict(state,
-                     tok=jnp.where(fin, 0, nxt).astype(jnp.int32),
-                     pos=jnp.where(fin, 0, pos).astype(jnp.int32),
-                     counts=counts,
-                     active=active & ~fin)
-    toks = jnp.where(active[:, None], true, 0).astype(jnp.int32)
+    with jax.named_scope("sample"):
+        if from_probs:
+            scores = jnp.log(jnp.maximum(scores, PROB_FLOOR))
+    with jax.named_scope("epilogue"):
+        active = state["active"]
+        offs = jnp.arange(L, dtype=jnp.int32)
+        # the vanilla trajectory's token at each offset: count c0 + j of the
+        # request's seeded stream (greedy rows take the argmax, same as ever)
+        true = jnp.stack(
+            [_sample_tokens(scores[:, j], state["temps"], state["top_ks"],
+                            state["seeds"], state["counts"] + j)
+             for j in range(L)], axis=1)                          # (B, L)
+        if L > 1:
+            match = (drafts == true[:, :L - 1]).astype(jnp.int32)
+            n_acc = jnp.sum(jnp.cumprod(match, axis=1), axis=1)   # (B,)
+        else:
+            n_acc = jnp.zeros((B,), jnp.int32)
+        m_max = n_acc + 1            # accepted drafts + the free bonus token
+        cnt_after = state["counts"][:, None] + 1 + offs[None, :]  # (B, L)
+        pos_after = state["pos"][:, None] + 1 + offs[None, :]
+        is_stop = jnp.any(true[:, :, None] == state["stop_ids"][:, None, :],
+                          axis=-1)
+        is_len = cnt_after >= state["max_new"][:, None]
+        is_trunc = pos_after >= cache_len
+        halt = is_stop | is_len | is_trunc                        # (B, L)
+        first_halt = jnp.where(jnp.any(halt, axis=1),
+                               jnp.argmax(halt, axis=1), L).astype(jnp.int32)
+        m = jnp.minimum(m_max, first_halt + 1)
+        m = jnp.where(active, m, 0).astype(jnp.int32)
+        halted = active & (first_halt < m_max)
+        code = jnp.where(is_stop, 1, jnp.where(is_len, 2, 3))
+        h = jnp.clip(first_halt, 0, L - 1)
+        done = jnp.where(halted,
+                         jnp.take_along_axis(code, h[:, None], axis=1)[:, 0],
+                         0).astype(jnp.int32)
+        fin = done > 0
+        counts = state["counts"] + m
+        pos = state["pos"] + m
+        last = jnp.take_along_axis(
+            true, jnp.maximum(m - 1, 0)[:, None], axis=1)[:, 0]
+        nxt = jnp.where(active, last, state["tok"]).astype(jnp.int32)
+        new_state = dict(state,
+                         tok=jnp.where(fin, 0, nxt).astype(jnp.int32),
+                         pos=jnp.where(fin, 0, pos).astype(jnp.int32),
+                         counts=counts,
+                         active=active & ~fin)
+        toks = jnp.where(active[:, None], true, 0).astype(jnp.int32)
     return new_state, toks, m, done

@@ -133,7 +133,7 @@ from repro.core.ensemble import (PROB_FLOOR, make_stacked_chunk_fns,
                                  make_stacked_verify, mix_expert_logits)
 from repro.models.model import Model
 from repro.obs import metrics as _obs_metrics
-from repro.obs.engine import EngineObs
+from repro.obs.engine import NULL_SPAN, EngineObs
 from repro.obs.trace import ADMIT_TID, merge_chrome
 from repro.serve.api import (EngineConfig, RequestOutput, SamplingParams,
                              TokenDelta, effective_page_block, stop_id_row)
@@ -390,6 +390,7 @@ class _SlotTable:
         self._parked: Dict[int, ParkedState] = {}   # rid -> parked state
         self._chunk_pick: Optional[int] = None      # this step's chunk slot
         self._step_ewma = 0.0        # EWMA step() wall time (TTFT model)
+        self._step_kind = "none"     # this step's dispatch, for its span
         self._tenant_stats: Dict[str, Dict[str, int]] = {}
         # telemetry bundle (PR 9): the always-on per-engine registry plus
         # the (default no-op) span recorder. stats() and the n_aborted /
@@ -621,26 +622,37 @@ class _SlotTable:
         / lockstep-decode dispatch. Streams back a ``RequestOutput`` for
         every request that progressed — finished ones first (admission
         retirements, then this step's), then the live per-token deltas in
-        slot order."""
+        slot order.
+
+        Traced, the step is a ``step:<kind>`` span tiled by its phases:
+        ``admit``, then (fused) ``schedule``, ``dispatch``, ``device_get``
+        and ``advance``, then ``outputs``."""
         t_start = time.perf_counter()
+        obs = self.obs
         self._chunk_pick = None      # this step's chunk pick, not yet made
-        self._admit_waiting()
-        finished = self._drain_admit_retired()
-        if self.active:
-            if self.sanitizer is not None:
-                self.sanitizer.begin_step()
-            finished += self._decode_step()
-            if self.sanitizer is not None:
-                self.sanitizer.check_step()
-        outs = [self._output(r) for r in finished]
-        for req in (self.slot_req[s] for s in range(self.n_slots)):
-            if req is not None and req.emitted < len(req.out):
-                outs.append(self._output(req))
-        self._obs_step()
-        # EWMA step time feeds the admission-control TTFT prediction
-        dt = time.perf_counter() - t_start
-        self._step_ewma = dt if self._step_ewma == 0.0 \
-            else 0.9 * self._step_ewma + 0.1 * dt
+        self._step_kind = "none"     # what the step dispatched, if anything
+        with obs.span("step") as step_span:
+            with obs.span("admit"):
+                self._admit_waiting()
+                finished = self._drain_admit_retired()
+            if self.active:
+                if self.sanitizer is not None:
+                    self.sanitizer.begin_step()
+                finished += self._decode_step()
+                if self.sanitizer is not None:
+                    self.sanitizer.check_step()
+            with obs.span("outputs"):
+                outs = [self._output(r) for r in finished]
+                for req in (self.slot_req[s] for s in range(self.n_slots)):
+                    if req is not None and req.emitted < len(req.out):
+                        outs.append(self._output(req))
+                self._obs_step()
+                # EWMA step time feeds the admission-control TTFT
+                # prediction
+                dt = time.perf_counter() - t_start
+                self._step_ewma = dt if self._step_ewma == 0.0 \
+                    else 0.9 * self._step_ewma + 0.1 * dt
+            step_span.kind(self._step_kind)
         return outs
 
     def abort(self, rid: int) -> Optional[RequestOutput]:
@@ -724,15 +736,26 @@ class _SlotTable:
             # release the pins (their contents stay reproducible: swap
             # payloads move host-side first, recompute re-prefills) and
             # retry the head once before declaring the pool too small
-            if self._parked and self._unpin_parked():
-                t0 = time.perf_counter()
-                if self._try_admit(req):
-                    self._dequeue(req)
-                    self._on_admitted(req, t0)
-                    return
+            if self._parked and self._unpin_parked() and \
+                    self._admit_one(req):
+                return
             raise RuntimeError(
                 f"cannot admit request {req.rid} even on an "
                 f"idle server — the KV block pool is too small for it")
+
+    def _admit_one(self, req: Request) -> bool:
+        """One admission attempt for a waiting request; on success it
+        leaves the queue and its admission is recorded. Traced, the
+        attempt is an ``admission`` span; a failed one writes no ring
+        event."""
+        with self.obs.span("admission", tid=ADMIT_TID) as span:
+            t0 = time.perf_counter()
+            if not self._try_admit(req):
+                span.drop()
+                return False
+            self._dequeue(req)
+            self._on_admitted(req, t0, span)
+            return True
 
     def _dequeue(self, req: Request) -> None:
         # identity scan: the Request dataclass __eq__ compares ndarray
@@ -745,11 +768,7 @@ class _SlotTable:
             admitted = False
             for i in range(min(len(self.waiting),
                                DEFAULT_ADMIT_LOOKAHEAD)):
-                req = self.waiting[i]
-                t0 = time.perf_counter()
-                if self._try_admit(req):
-                    self._dequeue(req)
-                    self._on_admitted(req, t0)
+                if self._admit_one(self.waiting[i]):
                     admitted = True
                     break            # restart the scan from the head
             if not admitted:
@@ -772,14 +791,9 @@ class _SlotTable:
                 break
             cand = {t: self._prefill_width(r) for t, r in heads.items()}
             pick = self._drr_admit.pick(cand)
-            req = heads[pick]
-            t0 = time.perf_counter()
-            if not self._try_admit(req):
+            if not self._admit_one(heads[pick]):
                 self._drr_admit.refund(pick, cand[pick])
                 blocked.add(pick)
-                continue
-            self._dequeue(req)
-            self._on_admitted(req, t0)
 
     # ------------------------------------------------------------------
     # Preemption: park / resume over the paged pool (repro.serve.qos)
@@ -863,23 +877,23 @@ class _SlotTable:
         resumed output is token-for-token identical: sampling is seeded
         per token index, independent of the schedule."""
         req = self.slot_req[slot]
-        mode = self.preemption
-        if self.prefilling[slot]:
-            mode = "requeue"
-            self.prefill_order.remove(slot)
-            self.prefilling[slot] = False
-            self.prefill_x[slot] = None
-            self.prefill_carry[slot] = None
-            self.prefill_keys[slot] = None
-            self.prefill_pos[slot] = 0
-            self.prefill_base[slot] = 0
-            self.prefill_width[slot] = 0
-            self._release(slot)
-        elif mode == "swap":
-            self._park_swap(slot, req)
-        else:
-            self._park_recompute(slot, req)
-        self._obs_preempted(slot, req, mode)
+        mode = "requeue" if self.prefilling[slot] else self.preemption
+        with self.obs.span("preempt", rid=req.rid, mode=mode):
+            if mode == "requeue":
+                self.prefill_order.remove(slot)
+                self.prefilling[slot] = False
+                self.prefill_x[slot] = None
+                self.prefill_carry[slot] = None
+                self.prefill_keys[slot] = None
+                self.prefill_pos[slot] = 0
+                self.prefill_base[slot] = 0
+                self.prefill_width[slot] = 0
+                self._release(slot)
+            elif mode == "swap":
+                self._park_swap(slot, req)
+            else:
+                self._park_recompute(slot, req)
+            self._obs_preempted(slot, req, mode)
         req.preemptions += 1
         tenant = tenant_of(req)
         self._tenant(tenant)["preemptions"] += 1
@@ -1037,13 +1051,13 @@ class _SlotTable:
                              "tenant": tenant_of(req)})
         req._obs_t_phase = 0.0
 
-    def _on_admitted(self, req: Request, t0: float) -> None:
+    def _on_admitted(self, req: Request, t0: float, span) -> None:
         """Telemetry boundary for one successful admission: stamp
         ``t_admit`` (queue delay ends here), close the request's
         ``queued`` span, and open its slot-resident phase. Requests that
         retired inside ``admit()`` (context-filling prompts, max_new == 1)
-        clamp the admission span to their ``t_done`` so a request's spans
-        always sum to its end-to-end latency."""
+        clamp the admission ``span`` to their ``t_done`` so a request's
+        spans always sum to its end-to-end latency."""
         t1 = req.t_done if req.finish_reason is not None \
             else time.perf_counter()
         resumed_from = getattr(req, "_obs_queued_from", 0.0)
@@ -1067,8 +1081,9 @@ class _SlotTable:
             tr.async_begin("queued", resumed_from or req.t_submit, req.rid,
                            args={"rid": req.rid})
             tr.async_end("queued", t0, req.rid)
-            tid = obs.slot_tid(slot) if slot is not None else ADMIT_TID
-            tr.complete("admission", t0, t1, tid, args={"rid": req.rid})
+            span.stamp(t0, t1, obs.slot_tid(slot) if slot is not None
+                       else ADMIT_TID)
+            span.note(rid=req.rid)
         if resumed_from:
             tenant = tenant_of(req)
             self._tenant(tenant)["resumes"] += 1
@@ -1577,63 +1592,84 @@ class _SlotTable:
         position advance all run on device; the host reads back only the
         (next_tok, done) pair — and the chunk's first token on a prefill's
         final chunk."""
-        dec = self.decoding
-        self._step_span = 1          # chunk/vanilla steps write one position
-        do_chunk = self.chunked and self._schedule_chunk()
+        obs = self.obs
+        with obs.span("schedule"):
+            dec = self.decoding
+            self._step_span = 1      # chunk/vanilla steps write one position
+            do_chunk = self.chunked and self._schedule_chunk()
+            if do_chunk:
+                slot, xc, start, length, cbt = self._chunk_args()
+                pick = self._pick_args(self.slot_req[slot])
         if not dec and not do_chunk:
             return []
-        if do_chunk:
-            slot, xc, start, length, cbt = self._chunk_args()
-            pick = self._pick_args(self.slot_req[slot])
-            if not dec:
-                t0 = time.perf_counter()
-                first = self._run_chunk_only(slot, xc, start, length, cbt,
-                                             pick)
-                t1 = self._obs_chunk_span(slot, start, t0)
-                retired = self._after_chunk_tok(
-                    slot, length, lambda: int(jax.device_get(first)[0]))
-                self.obs.step_timing("chunk", t0, t1)
-                return retired
-            self._grow_active()
-            dec = self.decoding      # growth may have preempted a victim
-            st = self._device_state()
-            t0 = time.perf_counter()
-            nxt, done, first = self._run_fused_chunk(st, slot, xc, start,
-                                                     length, cbt, pick)
-            t1 = self._obs_chunk_span(slot, start, t0)
-            nxt_h, done_h, first_h = jax.device_get((nxt, done, first))
-            self.obs.step_timing("decode+chunk", t0, t1)
-            retired = self._advance_fused(dec, nxt_h, done_h)
-            retired += self._after_chunk_tok(slot, length,
+        if do_chunk and not dec:
+            self._step_kind = "chunk"
+            first = self._dispatch(self._run_chunk_only, slot, xc, start,
+                                   length, cbt, pick,
+                                   inner=self._chunk_span(slot, start))
+            first_h = None
+            if int(self.prefill_pos[slot]) + length >= \
+                    int(self.prefill_width[slot]):
+                first_h = self._read_back(first)    # the final chunk only
+            with obs.span("advance"):
+                return self._after_chunk_tok(slot, length,
                                              lambda: int(first_h[0]))
-            return retired
-        if self._can_spec:
+        if not do_chunk and self._can_spec:
             retired = self._decode_step_spec(dec)
             if retired is not None:
                 return retired
             # pool can't cover the span this step: vanilla single token
-        self._grow_active()
-        dec = self.decoding          # growth may have preempted a victim
-        st = self._device_state()
-        t0 = time.perf_counter()
-        nxt, done = self._run_fused(st)
-        t1 = time.perf_counter()
-        nxt_h, done_h = jax.device_get((nxt, done))
-        self.obs.step_timing("decode", t0, t1)
-        return self._advance_fused(dec, nxt_h, done_h)
+        with obs.span("schedule"):
+            self._grow_active()
+            dec = self.decoding      # growth may have preempted a victim
+            st = self._device_state()
+        if do_chunk:
+            self._step_kind = "decode+chunk"
+            nxt, done, first = self._dispatch(
+                self._run_fused_chunk, st, slot, xc, start, length, cbt,
+                pick, inner=self._chunk_span(slot, start))
+            nxt_h, done_h, first_h = self._read_back((nxt, done, first))
+            with obs.span("advance"):
+                retired = self._advance_fused(dec, nxt_h, done_h)
+                retired += self._after_chunk_tok(slot, length,
+                                                 lambda: int(first_h[0]))
+            return retired
+        self._step_kind = "decode"
+        nxt, done = self._dispatch(self._run_fused, st)
+        nxt_h, done_h = self._read_back((nxt, done))
+        with obs.span("advance"):
+            return self._advance_fused(dec, nxt_h, done_h)
 
-    def _obs_chunk_span(self, slot: int, start: int, t0: float) -> float:
-        """Stamp the end of a chunk dispatch and (tracing) emit its
-        ``prefill_chunk[i]`` span on the slot's track. Returns the stamp —
-        the dispatch half of the step timing."""
-        t1 = time.perf_counter()
-        tr = self.obs.trace
-        if tr.enabled:
-            req = self.slot_req[slot]
-            tr.complete(f"prefill_chunk[{start // self.chunk}]", t0, t1,
-                        self.obs.slot_tid(slot),
-                        args={"rid": req.rid, "start": start})
-        return t1
+    def _dispatch(self, run, *args, inner=NULL_SPAN):
+        """``run(*args)``, the step's one jitted dispatch, in a
+        ``dispatch`` span (around ``inner``, a chunk's own span); the
+        always-on histogram times the call alone."""
+        with self.obs.span("dispatch"), inner:
+            t0 = time.perf_counter()
+            out = run(*args)
+            t1 = time.perf_counter()
+        self.obs.dispatch_s.observe(t1 - t0)
+        return out
+
+    def _read_back(self, arrays):
+        """The step's one ``jax.device_get``, in a ``device_get`` span and
+        timed by the always-on histogram."""
+        with self.obs.span("device_get"):
+            t0 = time.perf_counter()
+            host = jax.device_get(arrays)
+            t1 = time.perf_counter()
+        self.obs.readback_s.observe(t1 - t0)
+        return host
+
+    def _chunk_span(self, slot: int, start: int):
+        """The ``prefill_chunk[i]`` span of the chunk dispatched for
+        ``slot``, on the slot's track with its ``rid`` and ``start``."""
+        obs = self.obs
+        if not obs.trace.enabled:
+            return NULL_SPAN
+        return obs.span(f"prefill_chunk[{start // self.chunk}]",
+                        obs.slot_tid(slot), rid=self.slot_req[slot].rid,
+                        start=start)
 
     def _obs_phase_flip(self, slot: int, req: Request) -> None:
         """Prefill → decode transition: close the request's ``prefill``
@@ -1662,17 +1698,18 @@ class _SlotTable:
         falls back to the vanilla one-token step (the output trajectory
         is identical either way — only the step size changes)."""
         span = self.spec_len
-        if not self._grow_active_span(span):
-            return None
-        self._step_span = span       # sanitizer plan + _nb_live horizon
-        st = self._device_state()
-        drafts = self._draft_tokens(dec) if self._ngram is not None else None
-        t0 = time.perf_counter()
-        toks, n_emit, done = self._run_verify(st, drafts)
-        t1 = time.perf_counter()
-        toks_h, n_h, done_h = jax.device_get((toks, n_emit, done))
-        self.obs.step_timing("spec_verify", t0, t1)
-        return self._advance_span(dec, toks_h, n_h, done_h)
+        with self.obs.span("schedule"):
+            if not self._grow_active_span(span):
+                return None
+            self._step_span = span   # sanitizer plan + _nb_live horizon
+            st = self._device_state()
+            drafts = self._draft_tokens(dec) if self._ngram is not None \
+                else None
+        self._step_kind = "spec_verify"
+        toks, n_emit, done = self._dispatch(self._run_verify, st, drafts)
+        toks_h, n_h, done_h = self._read_back((toks, n_emit, done))
+        with self.obs.span("advance"):
+            return self._advance_span(dec, toks_h, n_h, done_h)
 
     def _draft_tokens(self, dec: List[int]) -> Array:
         """Host-side n-gram drafts, one row per slot. Idle / mid-prefill
@@ -1919,14 +1956,10 @@ class _SlotTable:
                 req._prefix_keys = (memo_key, keys)
             else:
                 keys = cached[1]
-            tr = self.obs.trace
-            t_m0 = time.perf_counter() if tr.enabled else 0.0
-            shared = self.prefix.match(keys, width)
-            if tr.enabled:
-                tr.complete("prefix_match", t_m0, time.perf_counter(),
-                            self.obs.slot_tid(slot),
-                            args={"rid": req.rid,
-                                  "hit_blocks": len(shared)})
+            with self.obs.span("prefix_match", self.obs.slot_tid(slot),
+                               rid=req.rid) as span:
+                shared = self.prefix.match(keys, width)
+                span.note(hit_blocks=len(shared))
             base = len(shared) * self.block_size
         if self.paged and not self._reserve(slot, width, shared=shared):
             return False
@@ -2183,28 +2216,32 @@ def make_chunk_fns(model: Model, cache_len: int, chunk: int, *,
     recurrent state flows through its carry — the lockstep decode's
     garbage updates to the mid-prefill slot's cache rows are overwritten by
     ``insert_direct`` at the transition."""
-    def prep(p, b):
+    def top1_prep(p, b):
         x = model.embed_prompt(p, b)                    # (1, W, D)
         return x, model.init_chunk_carry(p, b, cache_len)
 
-    chunk_only = jax.jit(
-        lambda p, c, carry, xc, start, ln, cbt: model.prefill_chunk(
-            p, c, carry, xc, start, ln, cbt, use_kernel=use_kernel))
+    def top1_chunk_logits(p, c, carry, xc, start, ln, cbt):
+        return model.prefill_chunk(p, c, carry, xc, start, ln, cbt,
+                                   use_kernel=use_kernel)
+
     if paged:
-        def fused(p, c, toks, pos, dbt, carry, xc, start, ln, cbt):
+        def top1_decode_chunk_logits(p, c, toks, pos, dbt, carry, xc,
+                                     start, ln, cbt):
             d_logits, c = model.decode_step_paged(p, c, toks, pos, dbt,
                                                   use_kernel=use_kernel)
             c_logits, carry, c = model.prefill_chunk(
                 p, c, carry, xc, start, ln, cbt, use_kernel=use_kernel)
             return d_logits, c_logits, carry, c
     else:
-        def fused(p, c, toks, pos, carry, xc, start, ln, cbt):
+        def top1_decode_chunk_logits(p, c, toks, pos, carry, xc, start, ln,
+                                     cbt):
             d_logits, c = model.decode_step(p, c, toks, pos,
                                             use_kernel=use_kernel)
             c_logits, carry, c = model.prefill_chunk(
                 p, c, carry, xc, start, ln, cbt, use_kernel=use_kernel)
             return d_logits, c_logits, carry, c
-    return jax.jit(prep), jax.jit(fused), chunk_only
+    return (jax.jit(top1_prep), jax.jit(top1_decode_chunk_logits),
+            jax.jit(top1_chunk_logits))
 
 
 def make_serve_fns(model: Model, cache_len: int, *, use_kernel: bool = False,
@@ -2213,42 +2250,47 @@ def make_serve_fns(model: Model, cache_len: int, *, use_kernel: bool = False,
     an explicit argument, so pods serving different experts of the same
     model SHARE one pair (one trace/compile instead of K). With ``paged``
     the decode fn takes the per-slot block tables as its last argument."""
-    prefill = jax.jit(
-        lambda p, b: model.prefill(p, b, cache_len, use_kernel=use_kernel))
+    def top1_prefill(p, b):
+        return model.prefill(p, b, cache_len, use_kernel=use_kernel)
+
     if paged:
-        decode = jax.jit(
-            lambda p, c, t, pos, bt: model.decode_step_paged(
-                p, c, t, pos, bt, use_kernel=use_kernel))
+        def top1_decode_logits(p, c, t, pos, bt):
+            return model.decode_step_paged(p, c, t, pos, bt,
+                                           use_kernel=use_kernel)
     else:
-        decode = jax.jit(
-            lambda p, c, t, pos: model.decode_step(p, c, t, pos,
-                                                   use_kernel=use_kernel))
-    return prefill, decode
+        def top1_decode_logits(p, c, t, pos):
+            return model.decode_step(p, c, t, pos, use_kernel=use_kernel)
+    return jax.jit(top1_prefill), jax.jit(top1_decode_logits)
 
 
 def make_fused_fns(model: Model, cache_len: int, chunk: int = 0, *,
                    use_kernel: bool = False, paged: bool = False):
     """The jitted fused-step function family one SlotServer runs on
     (shared across the pods of a top-1 DecentralizedSlotServer, like
-    ``make_serve_fns``). Returns ``(step, step_chunk, chunk_only)``:
+    ``make_serve_fns``). Returns ``(top1_fused_decode,
+    top1_fused_decode_chunk, top1_chunk_only)``, the names the device
+    trace shows them by:
 
-    * ``step(params, cache, state)`` → ``(cache, state, next_tok, done)``
-      — the WHOLE decode token (forward + sampling + stop/budget/context
-      checks + position advance) in one dispatch
+    * ``top1_fused_decode(params, cache, state)`` → ``(cache, state,
+      next_tok, done)`` — the WHOLE decode token (forward + sampling +
+      stop/budget/context checks + position advance) in one dispatch
       (``Model.fused_decode_step``);
-    * ``step_chunk(params, cache, state, carry, xc, start, length, cbt,
-      temp, top_k, seed)`` — the same with one co-scheduled prefill chunk
-      and its device-side first-token pick fused in;
-    * ``chunk_only(params, cache, carry, xc, start, length, cbt, temp,
+    * ``top1_fused_decode_chunk(params, cache, state, carry, xc, start,
+      length, cbt, temp, top_k, seed)`` — the same with one co-scheduled
+      prefill chunk and its device-side first-token pick fused in;
+    * ``top1_chunk_only(params, cache, carry, xc, start, length, cbt, temp,
       top_k, seed)`` → ``(first, carry, cache)`` — a chunk with nothing
       decoding. The last two are None when ``chunk == 0``.
     """
-    step = jax.jit(lambda p, c, st: model.fused_decode_step(
-        p, c, st, cache_len=cache_len, use_kernel=use_kernel, paged=paged))
-    if chunk <= 0:
-        return step, None, None
+    def top1_fused_decode(p, c, st):
+        return model.fused_decode_step(p, c, st, cache_len=cache_len,
+                                       use_kernel=use_kernel, paged=paged)
 
-    def step_chunk(p, c, st, carry, xc, start, ln, cbt, temp, top_k, seed):
+    if chunk <= 0:
+        return jax.jit(top1_fused_decode), None, None
+
+    def top1_fused_decode_chunk(p, c, st, carry, xc, start, ln, cbt, temp,
+                                top_k, seed):
         c, st, nxt, done = model.fused_decode_step(
             p, c, st, cache_len=cache_len, use_kernel=use_kernel,
             paged=paged)
@@ -2257,12 +2299,14 @@ def make_fused_fns(model: Model, cache_len: int, chunk: int = 0, *,
         first = pick_first(c_out, temp, top_k, seed)
         return c, st, nxt, done, first, carry
 
-    def chunk_only(p, c, carry, xc, start, ln, cbt, temp, top_k, seed):
+    def top1_chunk_only(p, c, carry, xc, start, ln, cbt, temp, top_k,
+                        seed):
         c_out, carry, c = model.prefill_chunk(p, c, carry, xc, start, ln,
                                               cbt, use_kernel=use_kernel)
         return pick_first(c_out, temp, top_k, seed), carry, c
 
-    return step, jax.jit(step_chunk), jax.jit(chunk_only)
+    return (jax.jit(top1_fused_decode), jax.jit(top1_fused_decode_chunk),
+            jax.jit(top1_chunk_only))
 
 
 def make_verify_fns(model: Model, cache_len: int, *,
@@ -2274,8 +2318,11 @@ def make_verify_fns(model: Model, cache_len: int, *,
     ``[committed token, drafts]`` plus the accept/reject epilogue in one
     dispatch (``Model.fused_verify_step``). Traces once per drafts width,
     which is fixed at ``spec_len - 1`` for an engine's lifetime."""
-    return jax.jit(lambda p, c, st, drafts: model.fused_verify_step(
-        p, c, st, drafts, cache_len=cache_len, use_kernel=use_kernel))
+    def top1_fused_verify(p, c, st, drafts):
+        return model.fused_verify_step(p, c, st, drafts,
+                                       cache_len=cache_len,
+                                       use_kernel=use_kernel)
+    return jax.jit(top1_fused_verify)
 
 
 class SlotServer(_SlotTable):
@@ -2329,7 +2376,8 @@ class SlotServer(_SlotTable):
                          qos=config.qos, preemption=config.preemption,
                          obs=EngineObs(pod=pod, trace=config.trace,
                                        trace_ring=config.trace_ring,
-                                       publish=config.metrics))
+                                       publish=config.metrics,
+                                       annotate=jax.profiler.TraceAnnotation))
         self.model, self.params = model, params
         self.use_kernel = use_kernel
         if self.paged:
@@ -2420,6 +2468,7 @@ class SlotServer(_SlotTable):
         do_chunk = self.chunked and self._schedule_chunk()
         if not dec and not do_chunk:
             return []
+        self._step_kind = "unfused"
         if do_chunk:
             slot, xc, start, length, cbt = self._chunk_args()
             if not dec:
@@ -2496,7 +2545,8 @@ class MixtureSlotServer(_SlotTable):
                          qos=config.qos, preemption=config.preemption,
                          obs=EngineObs(pod=pod, trace=config.trace,
                                        trace_ring=config.trace_ring,
-                                       publish=config.metrics))
+                                       publish=config.metrics,
+                                       annotate=jax.profiler.TraceAnnotation))
         self._seq_axis = 2      # embedded prompts carry K at axis 0
         self._from_probs = True  # the mixed scores are Eq. 27 probabilities
         self._needs_features = True   # admission routes on features
@@ -2514,20 +2564,21 @@ class MixtureSlotServer(_SlotTable):
                                        use_kernel=use_kernel)
             mix_decode = self._mix_decode
             if self.paged:
-                def fused(sp, c, toks, pos, w, dbt, carry, xc, start, ln,
-                          cbt, w_row):
+                def mixture_decode_chunk_probs(sp, c, toks, pos, w, dbt,
+                                               carry, xc, start, ln, cbt,
+                                               w_row):
                     probs, c = mix_decode(sp, c, toks, pos, w, dbt)
                     c_probs, carry, c = chunk_all(sp, c, carry, xc, start,
                                                   ln, cbt, w_row)
                     return probs, c_probs, carry, c
             else:
-                def fused(sp, c, toks, pos, w, carry, xc, start, ln, cbt,
-                          w_row):
+                def mixture_decode_chunk_probs(sp, c, toks, pos, w, carry,
+                                               xc, start, ln, cbt, w_row):
                     probs, c = mix_decode(sp, c, toks, pos, w)
                     c_probs, carry, c = chunk_all(sp, c, carry, xc, start,
                                                   ln, cbt, w_row)
                     return probs, c_probs, carry, c
-            self._fused_mix = jax.jit(fused)
+            self._fused_mix = jax.jit(mixture_decode_chunk_probs)
             self._chunk_only_mix = jax.jit(chunk_all)
         self.fused = config.fused_step
         if self.fused:
@@ -2554,6 +2605,10 @@ class MixtureSlotServer(_SlotTable):
         self.weights = np.zeros((n_slots, self.K), dtype=np.float32)
         self._mix = jax.jit(mix_expert_logits)
 
+        def mixture_route(features):
+            return router.route(features)
+        self._route = jax.jit(mixture_route)
+
     def admit(self, req: Request) -> bool:
         free = self.free_slots()
         if not free:
@@ -2567,19 +2622,13 @@ class MixtureSlotServer(_SlotTable):
                     req, slot, width,
                     lambda b: self._prep_all(self.stacked, b)):
                 return False
-            # device_get is the explicit sync for the host weights mirror
-            # — np.asarray of the device row was an implicit one (repro-
-            # lint host-sync)
-            w = jax.device_get(
-                self.router.route(jnp.asarray(req.features[None])))
-            self.weights[slot] = w[0]
+            self.weights[slot] = self._route_weights(req.features)[0]
             return True
         if not self._admission_precheck(req, slot, width):
             return False
         # route only once admission is paying for the prefill — a request
         # blocked on free KV blocks must not re-run the router every retry
-        w = jax.device_get(
-            self.router.route(jnp.asarray(req.features[None])))   # (1, K)
+        w = self._route_weights(req.features)                     # (1, K)
         logits, row_cache = self._prefill_all(self.stacked, req.batch())
         probs = self._mix(logits[:, :, -1], w)                    # (1, V)
         first = self._pick_first(req, probs[0], from_probs=True)
@@ -2590,6 +2639,20 @@ class MixtureSlotServer(_SlotTable):
         self.weights[slot] = w[0]
         self._admit_prefilled(slot, req, first, width, row_cache)
         return True
+
+    def _route_weights(self, features: np.ndarray) -> np.ndarray:
+        """The Eq. 28 router's (1, K) weights for one request, in a
+        ``route`` span and timed into ``serve_router_seconds``. The
+        ``device_get`` is the explicit sync for the host weights mirror —
+        ``np.asarray`` of the device row was an implicit one (repro-lint
+        host-sync)."""
+        obs = self.obs
+        with obs.span("route"):
+            t0 = time.perf_counter()
+            w = jax.device_get(self._route(jnp.asarray(features[None])))
+            t1 = time.perf_counter()
+        obs.router_s.observe(t1 - t0)
+        return w
 
     def _state_extras(self, st):
         st["weights"] = jnp.asarray(self.weights)
@@ -2639,6 +2702,7 @@ class MixtureSlotServer(_SlotTable):
         do_chunk = self.chunked and self._schedule_chunk()
         if not dec and not do_chunk:
             return []
+        self._step_kind = "unfused"
         if do_chunk:
             slot, xc, start, length, cbt = self._chunk_args()
             w_row = jnp.asarray(self.weights[slot:slot + 1])
@@ -2743,6 +2807,10 @@ class DecentralizedSlotServer:
                                     fused_fns=ffns, verify_fns=vfns,
                                     pod=k)
                          for k, p in enumerate(expert_params)]
+
+            def top1_route(features):
+                return router.top1(features)
+            self._route = jax.jit(top1_route)
         else:
             self.core = MixtureSlotServer(model, expert_params, router,
                                           config=config, pod=0)
@@ -2775,9 +2843,21 @@ class DecentralizedSlotServer:
         # submission is now, not when the pod sees the request — the
         # front-end routing dispatch must count toward TTFT
         req.t_submit = req.t_submit or time.perf_counter()
-        k = int(np.asarray(self.router.top1(
-            jnp.asarray(np.asarray(req.features)[None])))[0])
-        return self.pods[k].add_request(req)
+        return self.pods[self._route_one(req.features)].add_request(req)
+
+    def _route_one(self, features) -> int:
+        """The pod (top-1 expert) one request's features route to, timed
+        into that pod's ``serve_router_seconds``. Traced, a ``route`` span
+        naming the ``expert``; it is written with pod 0's telemetry, as
+        the pod is chosen inside it."""
+        with self.pods[0].obs.span("route") as span:
+            t0 = time.perf_counter()
+            k = int(jax.device_get(self._route(
+                jnp.asarray(np.asarray(features)[None])))[0])
+            t1 = time.perf_counter()
+            span.note(expert=k)
+        self.pods[k].obs.router_s.observe(t1 - t0)
+        return k
 
     def step(self) -> List[RequestOutput]:
         """One step of every pod (in pod order — admission then the fused

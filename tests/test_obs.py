@@ -18,6 +18,10 @@ Three strata:
   the decentralized server's merged export must keep one ``pid`` per
   pod; speculative serving must populate the draft-source and
   accept-length diagnostics.
+* **Spans on the profiler's clock** — ``EngineObs.span`` off reads no
+  clock and makes no annotation; on, the annotations nest exactly like
+  the ring's spans; the phase spans tile each pod step; every routing
+  call is timed and traced.
 """
 import json
 import math
@@ -337,6 +341,219 @@ def test_spans_tile_end_to_end_latency_exactly(dense_setup):
         assert phase[0]["ts"] == q_e["ts"]
         for a, b in zip(phase, phase[1:]):
             assert a["ts"] + a["dur"] == b["ts"], (rid, a, b)
+
+
+# ---------------------------------------------------------------------------
+# Spans on the profiler's clock: EngineObs.span and its annotation factory
+# ---------------------------------------------------------------------------
+
+# the benchmark harness's own annotations, which no program span may reuse
+HARNESS_NAMES = {"engine.step", "engine.add_request", "generator.wait"}
+# the spans that reach the profiler (request-lifetime spans do not)
+PROFILER_SPANS = {"step", "admit", "schedule", "dispatch", "device_get",
+                  "advance", "outputs", "admission", "prefix_match",
+                  "route", "preempt"}
+PHASE_ORDER = ("admit", "schedule", "dispatch", "device_get", "advance",
+               "outputs")
+
+
+class FakeAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each enter and
+    exit with the name and args the annotation carried."""
+
+    def __init__(self, log, name, **args):
+        self.log, self.name, self.args = log, name, dict(args)
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, self.args))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, self.args))
+
+    def set_metadata(self, **args):
+        self.args.update(args)
+
+
+def annotation_factory(log):
+    return lambda name, **args: FakeAnnotation(log, name, **args)
+
+
+def test_span_off_reads_no_clock_and_makes_no_annotation(monkeypatch):
+    import repro.obs.engine as engine_mod
+    from repro.obs.engine import NULL_SPAN
+    calls = []
+    monkeypatch.setattr(engine_mod.time, "perf_counter",
+                        lambda: calls.append(1) or 0.0)
+    log = []
+    obs = EngineObs(trace=False, annotate=annotation_factory(log))
+    for _ in range(100):
+        with obs.span("dispatch") as span:
+            span.note(rid=1)
+            span.kind("decode")
+            span.stamp(0.0, 1.0, SLOT_TID0)
+            span.drop()
+        assert span is NULL_SPAN
+    assert obs.span("admission", ADMIT_TID, rid=3) is NULL_SPAN
+    assert calls == [] and log == []
+    assert obs.trace.events() == []
+
+
+def test_span_on_writes_the_ring_and_the_annotation():
+    log = []
+    obs = EngineObs(pod=3, trace=True, annotate=annotation_factory(log))
+    with obs.span("step") as step:
+        with obs.span("prefix_match", SLOT_TID0, rid=7) as span:
+            span.note(hit_blocks=2)
+        with obs.span("admission", ADMIT_TID) as span:
+            span.drop()
+        step.kind("decode")
+    assert [e[:2] for e in log] == [
+        ("enter", "step"), ("enter", "prefix_match"),
+        ("exit", "prefix_match"), ("enter", "admission"),
+        ("exit", "admission"), ("exit", "step")]
+    assert log[-1][2] == {"pod": 3, "kind": "decode"}
+    assert log[2][2] == {"pod": 3, "rid": 7, "hit_blocks": 2}
+    xs = {e["name"]: e for e in obs.trace.events() if e["ph"] == "X"}
+    assert set(xs) == {"step:decode", "prefix_match"}   # dropped: no event
+    assert xs["prefix_match"]["args"] == {"rid": 7, "hit_blocks": 2}
+    assert xs["prefix_match"]["tid"] == SLOT_TID0 and \
+        xs["prefix_match"]["pid"] == 3
+    assert "args" not in xs["step:decode"]
+    # a span without an injected factory still writes the ring
+    plain = EngineObs(trace=True)
+    with plain.span("outputs"):
+        pass
+    assert [e["name"] for e in plain.trace.events()] == ["outputs"]
+
+
+def _ring_tree(evs, pid):
+    """[(name, parent)] of a pod's profiler-bound X spans in start order,
+    nesting by timestamps across the pod's tracks (one host thread)."""
+    spans = [e for e in evs if e["ph"] == "X" and e["pid"] == pid and
+             (e["name"].split(":")[0] in PROFILER_SPANS or
+              e["name"].startswith("prefill_chunk["))]
+    spans.sort(key=lambda e: (e["ts"], -e["dur"]))
+    out, stack = [], []
+    for e in spans:
+        while stack and e["ts"] >= stack[-1]["ts"] + stack[-1]["dur"]:
+            stack.pop()
+        out.append((e["name"], stack[-1]["name"] if stack else None))
+        stack.append(e)
+    return out
+
+
+def _annotation_tree(log):
+    """[(name, parent)] in enter order, ``step`` named ``step:<kind>`` as
+    the ring names it."""
+    def label(name, args):
+        return f"{name}:{args['kind']}" if name == "step" else name
+    out, stack = [], []
+    for ev in log:
+        if ev[0] == "enter":
+            out.append([ev, stack[-1] if stack else None])
+            stack.append(ev)
+        elif ev[0] == "exit":
+            stack.pop()
+    return [(label(e[1], e[2]), label(p[1], p[2]) if p else None)
+            for e, p in out]
+
+
+def test_profiler_spans_nest_like_the_ring(dense_setup, monkeypatch):
+    """Each span the program hands the profiler is entered and left like
+    the ring's X spans nest; on a strictly increasing clock the two trees
+    are the same, and no program span takes a harness annotation's name."""
+    import time as time_mod
+    clock = iter(range(1, 10 ** 9))
+    monkeypatch.setattr(time_mod, "perf_counter",
+                        lambda: next(clock) * 1e-3)
+    cfg, model, params = dense_setup
+    srv = SlotServer(model, params,
+                     config=chunked_config(trace=True, prefix_cache=True))
+    log = []
+    srv.obs.annotate = annotation_factory(log)
+    ps = prompts_of(cfg, (12, 9, 14, 7))
+    srv.serve([Request(i, p, 5) for i, p in enumerate(ps)])
+    ann = _annotation_tree(log)
+    ring = _ring_tree(srv.export_trace()["traceEvents"], 0)
+    assert ann == ring
+    names = {n for n, _ in ann}
+    assert not names & HARNESS_NAMES
+    assert {"admission", "prefix_match", "dispatch", "device_get"} <= names
+    assert any(n.startswith("prefill_chunk[") for n in names)
+    assert all(e[2]["pod"] == 0 for e in log if e[0] == "enter")
+
+
+def test_phase_spans_tile_each_step(dense_setup):
+    """Each ``step:<kind>`` span on the engine-steps track holds its phases
+    in order, disjoint, and covering it but for the few stamps between
+    them; the always-on histograms count one dispatch per ``dispatch`` span
+    and one readback per ``device_get`` span (an intermediate chunk reads
+    nothing back)."""
+    cfg, model, params = dense_setup
+    srv, reqs, _ = serve_traced(model, params,
+                                prompts_of(cfg, (12, 9, 30, 7)))
+    evs = [e for e in srv.export_trace()["traceEvents"]
+           if e["ph"] == "X" and e["tid"] == STEP_TID]
+    steps = [e for e in evs if e["name"].startswith("step:")]
+    phases = [e for e in evs if e["name"] in PHASE_ORDER]
+    kinds = {s["name"] for s in steps}
+    assert {"step:chunk", "step:decode+chunk", "step:decode"} <= kinds
+    covered = total = 0
+    for s in steps:
+        lo, hi = s["ts"], s["ts"] + s["dur"]
+        inner = sorted((p for p in phases if lo <= p["ts"] < hi),
+                       key=lambda p: p["ts"])
+        seq = [p["name"] for p in inner]
+        assert seq[0] == "admit" and seq[-1] == "outputs", seq
+        assert [PHASE_ORDER.index(n) for n in seq] == sorted(
+            PHASE_ORDER.index(n) for n in seq), seq
+        if s["name"] == "step:none":
+            assert seq == ["admit", "outputs"]
+        else:
+            assert "dispatch" in seq and "advance" in seq, seq
+        for a, b in zip(inner, inner[1:]):
+            assert a["ts"] + a["dur"] <= b["ts"], (a, b)
+        assert inner[-1]["ts"] + inner[-1]["dur"] <= hi
+        covered += sum(p["dur"] for p in inner)
+        total += s["dur"]
+    assert covered >= 0.95 * total, (covered, total)
+    n = {k: sum(1 for p in phases if p["name"] == k)
+         for k in ("dispatch", "device_get")}
+    assert srv.obs.dispatch_s.count == n["dispatch"]
+    assert srv.obs.readback_s.count == n["device_get"] < n["dispatch"]
+
+
+def test_router_seconds_time_every_routing_call(dense_setup):
+    """``serve_router_seconds`` observes each front-end routing call on
+    the pod the request went to (top-1), and each admission's routing on
+    the mixture core; traced, each call is a ``route`` span."""
+    cfg, model, params = dense_setup
+    from repro.core.router import CentroidRouter, RouterConfig
+    K = 2
+    rng = np.random.default_rng(1)
+    experts = [model.init(jax.random.PRNGKey(k)) for k in range(K)]
+    router = CentroidRouter(
+        jax.numpy.asarray(rng.normal(size=(K, 8)), jax.numpy.float32),
+        RouterConfig(top_k=2))
+    ps = prompts_of(cfg, (10, 9, 11, 8, 12))
+    feats = rng.normal(size=(len(ps), 8)).astype(np.float32)
+    for strategy in ("top1", "mixture"):
+        srv = DecentralizedSlotServer(
+            model, experts, router,
+            config=chunked_config(trace=True, strategy=strategy))
+        srv.serve([Request(i, p, 3, features=feats[i])
+                   for i, p in enumerate(ps)])
+        engines = srv._engines()
+        counts = [e.obs.router_s.count for e in engines]
+        assert sum(counts) == len(ps), (strategy, counts)
+        if strategy == "top1":
+            assert counts == [e.obs.submitted.value for e in engines]
+        assert all(e.obs.router_s.sum > 0 for e in engines
+                   if e.obs.router_s.count)
+        routes = [e for e in srv.export_trace()["traceEvents"]
+                  if e["ph"] == "X" and e["name"] == "route"]
+        assert len(routes) == len(ps)
 
 
 def test_tracing_is_observation_only(dense_setup):
