@@ -86,24 +86,27 @@ def run(_settings=None):
     # defeats the trace cache and retraces every rep (repro-lint
     # retrace-hazard)
     jit_ref = jax.jit(ref.paged_decode_attention_ref)
+    layer = jnp.int32(0)              # a one-layer pool
     for block in (8, 16, 32):
         NB = span // block
         P = B * NB + 2
-        kpool = jax.random.normal(kp[1], (P, KV, block, dh), jnp.float32)
-        vpool = jax.random.normal(kp[2], (P, KV, block, dh), jnp.float32)
+        kpool = jax.random.normal(kp[1], (1, P, KV, block, dh), jnp.float32)
+        vpool = jax.random.normal(kp[2], (1, P, KV, block, dh), jnp.float32)
         bt = jnp.arange(1, B * NB + 1, dtype=jnp.int32).reshape(B, NB)
-        oracle = ref.paged_decode_attention_ref(qp, kpool, vpool, ppos, bt)
+        oracle = ref.paged_decode_attention_ref(qp, kpool, vpool, layer,
+                                                ppos, bt)
         for bps in (1, 2, 4):
-            got = ops.paged_decode_attention(qp, kpool, vpool, ppos, bt,
-                                             blocks_per_step=bps)
+            got = ops.paged_decode_attention(qp, kpool, vpool, layer, ppos,
+                                             bt, blocks_per_step=bps)
             assert jnp.allclose(got, oracle, atol=1e-5), (block, bps)
             rows.append((f"paged_decode_b{block}_bps{bps}_pallas",
                          _time(lambda a, b_, c_, p, t, n=bps:
                                ops.paged_decode_attention(
-                                   a, b_, c_, p, t, blocks_per_step=n),
+                                   a, b_, c_, layer, p, t,
+                                   blocks_per_step=n),
                                qp, kpool, vpool, ppos, bt), ker))
         rows.append((f"paged_decode_b{block}_ref",
-                     _time(jit_ref, qp, kpool, vpool, ppos, bt),
+                     _time(jit_ref, qp, kpool, vpool, layer, ppos, bt),
                      xla))
 
     print(f"\n== Kernel microbenchmarks ({dev.platform} {dev.device_kind}; "

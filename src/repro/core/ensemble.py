@@ -87,7 +87,7 @@ def stack_experts_for_decode(expert_params):
     The K dim still shards over ``pod`` regardless of its position.
 
     Returns ``(stacked, in_axes)`` where ``in_axes`` is the per-leaf vmap
-    axis tree to pass to ``jax.vmap``.
+    axis tree to pass to ``jax.vmap`` (``decode_param_axes``).
 
     Each leaf is stacked straight into its decode-layout axis, one leaf
     at a time, with no K-major intermediate to transpose. Experts held on
@@ -97,7 +97,17 @@ def stack_experts_for_decode(expert_params):
     arrays are uploaded one leaf at a time, so the device holds the
     stacked copy plus one leaf's K inputs.
     """
-    axes = jax.tree.map(lambda _: 0, expert_params[0])
+    axes = decode_param_axes(expert_params[0])
+    stacked = jax.tree.map(lambda ax, *leaves: jnp.stack(leaves, axis=ax),
+                           axes, *expert_params)
+    return stacked, axes
+
+
+def decode_param_axes(params):
+    """The expert axis of each parameter leaf in the decode layout of
+    ``stack_experts_for_decode``: 1 in the scanned layer stacks (the
+    ``blocks`` subtrees), 0 everywhere else."""
+    axes = jax.tree.map(lambda _: 0, params)
     if isinstance(axes, dict) and "blocks" in axes:
         axes = dict(axes)
         axes["blocks"] = jax.tree.map(lambda _: 1, axes["blocks"])
@@ -105,15 +115,21 @@ def stack_experts_for_decode(expert_params):
             axes["encoder"] = dict(axes["encoder"])
             axes["encoder"]["blocks"] = jax.tree.map(
                 lambda _: 1, axes["encoder"]["blocks"])
-    stacked = jax.tree.map(lambda ax, *leaves: jnp.stack(leaves, axis=ax),
-                           axes, *expert_params)
-    return stacked, axes
+    return axes
 
 
-def stacked_cache_axes(cache_like):
-    """vmap axis tree for a stacked decode cache: every cache leaf carries
-    its scan (layer/group) dim first, so the expert dim lives at axis 1."""
-    return jax.tree.map(lambda _: 1, cache_like)
+def stacked_cache_axes(model, paged: bool):
+    """vmap axis tree for a stacked decode cache. A leaf the layer loop
+    scans (every leaf of the contiguous cache; the recurrent state and
+    cross-attention K/V of the paged one) carries its scan (layer/group)
+    dim first, so the expert dim lives at axis 1. A paged pool leaf rides
+    the layer loop's carry whole and is updated in place, so it leads with
+    the expert dim: a carry vmapped at any other axis than the stored one
+    makes XLA transpose the whole pool on the way in and out of every
+    step."""
+    # which leaves page is a property of the family, not of the block size
+    seq = model.cache_spec(1).paged.seq_axes
+    return jax.tree.map(lambda s: 0 if paged and s >= 0 else 1, seq)
 
 
 def make_stacked_serving(model, expert_params, cache_len: int, *,
@@ -131,15 +147,14 @@ def make_stacked_serving(model, expert_params, cache_len: int, *,
       ``(Eq. 27 mixed probabilities (B, V), new caches)`` — ONE vmapped
       ``decode_step`` over the K dim with the mixing fused into the jit.
 
-    With ``paged`` the caches are the block-pool layout (pool leaves carry
-    the K dim at axis 1, exactly like the direct leaves) and the decode
-    takes the per-slot block tables as a trailing argument, shared across
-    all K experts (``in_axes=None`` under the vmap).
+    With ``paged`` the caches are the block-pool layout (pool leaves lead
+    with the K dim, ``stacked_cache_axes``), the decode takes the per-slot
+    block tables as a trailing argument, shared across all K experts
+    (``in_axes=None`` under the vmap), and it consumes (donates) its
+    caches argument: the caller rebinds the returned caches.
     """
     stacked, param_axes = stack_experts_for_decode(expert_params)
-    # axis tree only depends on the cache STRUCTURE (paged and contiguous
-    # caches share it): every leaf carries K at axis 1, after its scan dim
-    cache_axes = stacked_cache_axes(model.cache_shapes(1, cache_len))
+    cache_axes = stacked_cache_axes(model, paged)
 
     def mixture_prefill(stacked_p, batch):
         return jax.vmap(
@@ -166,7 +181,8 @@ def make_stacked_serving(model, expert_params, cache_len: int, *,
             return mix_expert_logits(logits, weights), caches
 
     return (stacked, param_axes, jax.jit(mixture_prefill),
-            jax.jit(mixture_decode_probs))
+            jax.jit(mixture_decode_probs,
+                    donate_argnums=(1,) if paged else ()))
 
 
 def make_stacked_chunk_fns(model, stacked, param_axes, cache_len: int,
@@ -180,9 +196,9 @@ def make_stacked_chunk_fns(model, stacked, param_axes, cache_len: int,
       every expert owns its embedding table; admission slices off any cached
       prefix and pre-splits the suffix into per-chunk tensors, keeping the
       chunk step dispatch-free — per-expert chunk carries with the K dim
-      at axis 1 of every leaf, the same slot the stacked cache keeps it
-      in, so ``CacheSpec.shifted(1).insert_direct`` splices the finished
-      carry without a transpose);
+      at axis 1 of every leaf, the slot the stacked cache keeps it in for
+      every direct leaf, so ``CacheSpec.shifted(1).insert_direct`` splices
+      the finished carry without a transpose);
     * ``mixture_chunk_probs(stacked, caches, carry, xc, start, length,
       block_table, weights)`` → (Eq. 27 mixed next-token probs (1, V) at
       the chunk's last valid position, new carry, new caches) — ONE vmapped
@@ -192,9 +208,9 @@ def make_stacked_chunk_fns(model, stacked, param_axes, cache_len: int,
     ``mixture_chunk_probs`` is returned un-jitted so the mixture server can
     fuse it with the decode step into a single dispatch; ``mixture_prep``
     is jitted (it runs once per admission, retracing per padded prompt
-    width).
+    width). Chunked prefill runs on the paged cache only.
     """
-    cache_axes = stacked_cache_axes(model.cache_shapes(1, cache_len))
+    cache_axes = stacked_cache_axes(model, True)
 
     def mixture_prep(stacked_p, batch):
         x = jax.vmap(lambda p: model.embed_prompt(p, batch),
@@ -240,11 +256,13 @@ def make_stacked_fused(model, param_axes, cache_len: int, *,
     * ``mixture_chunk_only(...)`` — the chunk + pick without a decode.
 
     The last two are None without ``chunk_all`` (pass the un-jitted chunk
-    fn from ``make_stacked_chunk_fns``).
+    fn from ``make_stacked_chunk_fns``). Each consumes (donates) its
+    caches argument, so the pool is updated in place; the caller rebinds
+    the returned caches.
     """
     # function-level import: serve.fused imports PROB_FLOOR from here
     from repro.serve.fused import decode_epilogue, pick_first
-    cache_axes = stacked_cache_axes(model.cache_shapes(1, cache_len))
+    cache_axes = stacked_cache_axes(model, paged)
 
     if paged:
         def mix(stacked_p, caches, st):
@@ -271,7 +289,7 @@ def make_stacked_fused(model, param_axes, cache_len: int, *,
         return caches, st, nxt, done
 
     if chunk_all is None:
-        return jax.jit(mixture_fused_decode), None, None
+        return jax.jit(mixture_fused_decode, donate_argnums=(1,)), None, None
 
     def mixture_fused_decode_chunk(stacked_p, caches, st, carry, xc, start,
                                    length, cbt, w_row, temp, top_k, seed):
@@ -290,8 +308,9 @@ def make_stacked_fused(model, param_axes, cache_len: int, *,
         first = pick_first(c_probs, temp, top_k, seed, from_probs=True)
         return first, carry, caches
 
-    return (jax.jit(mixture_fused_decode),
-            jax.jit(mixture_fused_decode_chunk), jax.jit(mixture_chunk_only))
+    return (jax.jit(mixture_fused_decode, donate_argnums=(1,)),
+            jax.jit(mixture_fused_decode_chunk, donate_argnums=(1,)),
+            jax.jit(mixture_chunk_only, donate_argnums=(1,)))
 
 
 def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
@@ -312,7 +331,7 @@ def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
     draft cache to maintain, no catch-up forward. The draft loop runs
     ``spec_len - 1`` sequential greedy expert-0 ``decode_step_paged``
     micro-steps on a locally-threaded copy of that slice, then DISCARDS
-    it: the vmapped verify re-scatters all K experts' K/V at every span
+    it: the vmapped verify re-writes all K experts' K/V at every span
     position, so the draft's tentative writes never touch the real pool.
     Returns a jitted ``verify(stacked, caches, state)`` →
     ``(caches, state, toks, n_emit, done)``.
@@ -320,10 +339,12 @@ def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
     With ``expert_draft=False`` the drafts arrive as an argument (the
     scheduler's host-side n-gram proposer):
     ``verify(stacked, caches, state, drafts)`` with the same outputs.
+    Either consumes (donates) its caches argument; the draft's copy is
+    made inside the program, before the verify writes the pool.
     """
     # function-level import: serve.fused imports PROB_FLOOR from here
     from repro.serve.fused import verify_epilogue
-    cache_axes = stacked_cache_axes(model.cache_shapes(1, cache_len))
+    cache_axes = stacked_cache_axes(model, True)
 
     def mixture_fused_verify(stacked_p, caches, st, drafts):
         tokens = jnp.concatenate([st["tok"][:, None], drafts], axis=1)
@@ -339,7 +360,7 @@ def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
         return caches, st, toks, n_emit, done
 
     if not expert_draft:
-        return jax.jit(mixture_fused_verify)
+        return jax.jit(mixture_fused_verify, donate_argnums=(1,))
 
     def mixture_fused_verify_self_draft(stacked_p, caches, st):
         draft_p = jax.tree.map(lambda leaf, ax: jnp.take(leaf, 0, axis=ax),
@@ -357,7 +378,7 @@ def make_stacked_verify(model, param_axes, cache_len: int, spec_len: int, *,
         drafts = jnp.stack(drafts, axis=1)               # (B, L-1)
         return mixture_fused_verify(stacked_p, caches, st, drafts)
 
-    return jax.jit(mixture_fused_verify_self_draft)
+    return jax.jit(mixture_fused_verify_self_draft, donate_argnums=(1,))
 
 
 def select_expert_params(stacked_params, expert_idx: Array):

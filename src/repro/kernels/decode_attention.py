@@ -16,11 +16,13 @@ share the kernel body:
 * contiguous — K/V are (B, S, KV, dh) slot rows, transposed head-major to
   (B, KV, S, dh) by the wrapper; the ki-th grid step reads the ki-th
   sequence block of row b;
-* paged — K/V live in a shared head-major (P, KV, block, dh) block pool
-  and the ki-th grid step reads physical block ``block_tables[b, ki]``:
-  the per-slot block table is a scalar-prefetch operand, so the index map
-  resolves the indirection at DMA-issue time and the body never sees it
-  (the classic paged-attention gather). Unallocated table entries point at
+* paged — K/V live in a shared head-major (L, P, KV, block, dh) block
+  pool holding every layer, and the ki-th grid step of layer ``layer``
+  reads physical block ``block_tables[b, ki]``: the layer and the per-slot
+  block table are scalar-prefetch operands, so the index map resolves the
+  indirection at DMA-issue time and the body never sees it (the classic
+  paged-attention gather). The caller's layer loop carries the whole pool
+  and never slices a layer out of it. Unallocated table entries point at
   the reserved scratch block 0 and are killed by the position mask.
 """
 from __future__ import annotations
@@ -138,7 +140,7 @@ def decode_attention(q: Array, k: Array, v: Array, pos: Array, *,
     return out.reshape(B, H, dh)
 
 
-def _paged_decode_kernel(pos_ref, bt_ref, q_ref, *refs,
+def _paged_decode_kernel(pos_ref, bt_ref, layer_ref, q_ref, *refs,
                          scale: float, block: int, window: int, s_log: int,
                          bps: int, nb: int):
     """Same online-softmax body as ``_decode_kernel``; the physical-block
@@ -188,7 +190,7 @@ def _paged_decode_kernel(pos_ref, bt_ref, q_ref, *refs,
                              jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _chunk_prefill_kernel(start_ref, bt_ref, q_ref, *refs,
+def _chunk_prefill_kernel(start_ref, bt_ref, layer_ref, q_ref, *refs,
                           scale: float, block: int, group: int, C: int,
                           bps: int, nb: int):
     """Prefix-aware chunked-prefill flash attention over PAGED blocks.
@@ -199,7 +201,7 @@ def _chunk_prefill_kernel(start_ref, bt_ref, q_ref, *refs,
     index maps (scalar-prefetched block table), exactly like the paged
     decode kernel, with the same ``blocks_per_step`` sub-tiling (the
     horizon here is the last query position's block). The chunk's own K/V
-    were scattered into the pool before the call, so the single fence
+    were written into the pool before the call, so the single fence
     ``key position ≤ query position`` covers both the prefix and
     within-chunk causality.
     """
@@ -252,17 +254,18 @@ def _chunk_prefill_kernel(start_ref, bt_ref, q_ref, *refs,
 
 
 def chunk_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
-                            start: Array, block_table: Array, *,
-                            blocks_per_step: int = 1,
+                            layer: Array, start: Array, block_table: Array,
+                            *, blocks_per_step: int = 1,
                             interpret: bool = False) -> Array:
     """q: (C,H,dh) one request's chunk queries; k_pool,v_pool:
-    (P,KV,block,dh) with the chunk's K/V already scattered in; start: ()
-    int32 absolute position of chunk row 0; block_table: (NB,) int32 →
-    (C,H,dh).
+    (L,P,KV,block,dh) with the chunk's K/V already written in; layer: ()
+    int32 the pool layer to read; start: () int32 absolute position of
+    chunk row 0; block_table: (NB,) int32 → (C,H,dh).
 
     Grid = (kv_heads, ⌈NB / blocks_per_step⌉ logical-block groups);
-    ``start`` and the block table are scalar-prefetch operands so the K/V
-    index maps resolve the physical block at DMA-issue time. As in
+    ``start``, the block table and ``layer`` are scalar-prefetch operands
+    so the K/V index maps resolve the physical block at DMA-issue time. As
+    in
     ``paged_decode_attention``, each of the ``blocks_per_step`` sub-tiles
     is its own operand whose index map clamps the fetched logical index to
     the chunk's horizon block ``(start + C - 1) // block`` — dead blocks
@@ -271,7 +274,7 @@ def chunk_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
     position fence.
     """
     C, H, dh = q.shape
-    KV, block = k_pool.shape[1], k_pool.shape[2]
+    KV, block = k_pool.shape[2], k_pool.shape[3]
     NB = block_table.shape[0]
     assert H % KV == 0
     group = H // KV
@@ -283,32 +286,33 @@ def chunk_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
         .reshape(KV, C * group, dh)
 
     def kv_spec(j):
-        def imap(h, kc, start_r, bt_r):
+        def imap(h, kc, start_r, bt_r, l_r):
             # repro: bounds bt_r holds pool block ids < P (the pool's
-            # leading dim) — the allocator only writes ids it owns and
+            # block dim) — the allocator only writes ids it owns and
             # masks unallocated table rows to the reserved scratch block
             # 0; ki is clamped to NB - 1 above, so bt_r[ki] never reads
             # past the table
+            # repro: bounds l_r[0] is the layer loop's scanned index < L
             ki = jnp.minimum(jnp.minimum(kc * bps + j,
                                          (start_r[0] + C - 1) // block),
                              NB - 1)
-            return (bt_r[ki], h, 0, 0)
-        return pl.BlockSpec((1, 1, block, dh), imap)
+            return (l_r[0], bt_r[ki], h, 0, 0)
+        return pl.BlockSpec((pl.Squeezed(), 1, 1, block, dh), imap)
 
     kernel = functools.partial(_chunk_prefill_kernel, scale=scale,
                                block=block, group=group, C=C,
                                bps=bps, nb=NB)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                        # start, block_table
+        num_scalar_prefetch=3,                # start, block_table, layer
         grid=(KV, nkc),
         in_specs=[
             pl.BlockSpec((1, C * group, dh),
-                         lambda h, kc, start_r, bt_r: (h, 0, 0)),       # q
+                         lambda h, kc, *_: (h, 0, 0)),                  # q
             *[kv_spec(j) for j in range(bps)],                          # k
             *[kv_spec(j) for j in range(bps)],                          # v
         ],
         out_specs=pl.BlockSpec((1, C * group, dh),
-                               lambda h, kc, start_r, bt_r: (h, 0, 0)),
+                               lambda h, kc, *_: (h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((C * group, 1), jnp.float32),
             pltpu.VMEM((C * group, 1), jnp.float32),
@@ -321,13 +325,14 @@ def chunk_prefill_attention(q: Array, k_pool: Array, v_pool: Array,
         out_shape=jax.ShapeDtypeStruct((KV, C * group, dh), q.dtype),
         interpret=interpret,
     )(jnp.asarray(start, jnp.int32).reshape(1),
-      block_table.astype(jnp.int32), qg,
+      block_table.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg,
       *([k_pool] * bps), *([v_pool] * bps))
     return jnp.transpose(out.reshape(KV, C, group, dh),
                          (1, 0, 2, 3)).reshape(C, H, dh)
 
 
-def _paged_verify_kernel(pos_ref, bt_ref, q_ref, *refs,
+def _paged_verify_kernel(pos_ref, bt_ref, layer_ref, q_ref, *refs,
                          scale: float, block: int, group: int, L: int,
                          bps: int, nb: int):
     """Speculative span verify over PAGED blocks — the chunk-prefill body
@@ -338,7 +343,7 @@ def _paged_verify_kernel(pos_ref, bt_ref, q_ref, *refs,
     the LOGICAL block index — the physical indirection happened in the
     scalar-prefetched index maps, with the same ``blocks_per_step``
     sub-tiling as the paged decode kernel. The span's own K/V were
-    scattered into the pool before the call, so the single fence
+    written into the pool before the call, so the single fence
     ``key position ≤ pos + row offset`` covers the committed prefix AND
     within-span causality; rejected-tail keys at later offsets are hidden
     from every accepted row by the same rule.
@@ -393,18 +398,19 @@ def _paged_verify_kernel(pos_ref, bt_ref, q_ref, *refs,
 
 
 def paged_verify_attention(q: Array, k_pool: Array, v_pool: Array,
-                           pos: Array, block_tables: Array, *,
-                           blocks_per_step: int = 1,
+                           layer: Array, pos: Array, block_tables: Array,
+                           *, blocks_per_step: int = 1,
                            interpret: bool = False) -> Array:
     """q: (B,L,H,dh) span queries (row ℓ of slot b sits at absolute
-    position ``pos[b] + ℓ``, its K/V already scattered into the pool);
-    k_pool,v_pool: (P,KV,block,dh); pos: (B,) int32; block_tables: (B,NB)
-    int32 → (B,L,H,dh).
+    position ``pos[b] + ℓ``, its K/V already written into the pool);
+    k_pool,v_pool: (layers,P,KV,block,dh); layer: () int32 the pool layer
+    to read; pos: (B,) int32; block_tables: (B,NB) int32 → (B,L,H,dh).
 
     Grid = (batch, kv_heads, ⌈NB / blocks_per_step⌉), one (L·group, dh)
     query tile per slot per KV head (span offsets ride the sublane axis
     next to the GQA group, exactly like the chunk-prefill kernel's rows).
-    ``pos`` and the block tables are scalar-prefetch operands; each of the
+    ``pos``, the block tables and ``layer`` are scalar-prefetch operands;
+    each of the
     ``blocks_per_step`` K/V sub-tiles is its own operand whose index map
     clamps the fetched logical index to the span's horizon block
     ``(pos + L - 1) // block`` — dead blocks alias the horizon block and
@@ -412,7 +418,7 @@ def paged_verify_attention(q: Array, k_pool: Array, v_pool: Array,
     are not supported: the scheduler only routes windowless models here.
     """
     B, L, H, dh = q.shape
-    P, KV, block = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    KV, block = k_pool.shape[2], k_pool.shape[3]
     NB = block_tables.shape[1]
     assert H % KV == 0
     group = H // KV
@@ -424,30 +430,31 @@ def paged_verify_attention(q: Array, k_pool: Array, v_pool: Array,
         .reshape(B, KV, L * group, dh)
 
     def kv_spec(j):
-        def imap(b, h, kc, pos_r, bt_r):
+        def imap(b, h, kc, pos_r, bt_r, l_r):
             # repro: bounds bt_r holds pool block ids < P (the pool's
-            # leading dim) — allocator invariant; ki is clamped to NB - 1,
+            # block dim) — allocator invariant; ki is clamped to NB - 1,
             # so bt_r[b, ki] stays in-table
+            # repro: bounds l_r[0] is the layer loop's scanned index < L
             ki = jnp.minimum(jnp.minimum(kc * bps + j,
                                          (pos_r[b] + L - 1) // block),
                              NB - 1)
-            return (bt_r[b, ki], h, 0, 0)
-        return pl.BlockSpec((1, 1, block, dh), imap)
+            return (l_r[0], bt_r[b, ki], h, 0, 0)
+        return pl.BlockSpec((pl.Squeezed(), 1, 1, block, dh), imap)
 
     kernel = functools.partial(_paged_verify_kernel, scale=scale,
                                block=block, group=group, L=L,
                                bps=bps, nb=NB)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                        # pos, block_tables
+        num_scalar_prefetch=3,                # pos, block_tables, layer
         grid=(B, KV, nkc),
         in_specs=[
             pl.BlockSpec((1, 1, L * group, dh),
-                         lambda b, h, kc, pos_r, bt_r: (b, h, 0, 0)),   # q
+                         lambda b, h, kc, *_: (b, h, 0, 0)),            # q
             *[kv_spec(j) for j in range(bps)],                          # k
             *[kv_spec(j) for j in range(bps)],                          # v
         ],
         out_specs=pl.BlockSpec((1, 1, L * group, dh),
-                               lambda b, h, kc, pos_r, bt_r: (b, h, 0, 0)),
+                               lambda b, h, kc, *_: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((L * group, 1), jnp.float32),
             pltpu.VMEM((L * group, 1), jnp.float32),
@@ -459,22 +466,25 @@ def paged_verify_attention(q: Array, k_pool: Array, v_pool: Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, L * group, dh), q.dtype),
         interpret=interpret,
-    )(pos.astype(jnp.int32), block_tables.astype(jnp.int32), qg,
+    )(pos.astype(jnp.int32), block_tables.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg,
       *([k_pool] * bps), *([v_pool] * bps))
     return jnp.transpose(out.reshape(B, KV, L, group, dh),
                          (0, 2, 1, 3, 4)).reshape(B, L, H, dh)
 
 
 def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
-                           pos: Array, block_tables: Array, *,
-                           window: int = 0, blocks_per_step: int = 1,
+                           layer: Array, pos: Array, block_tables: Array,
+                           *, window: int = 0, blocks_per_step: int = 1,
                            interpret: bool = False) -> Array:
-    """q: (B,H,dh); k_pool,v_pool: (P,KV,block,dh); pos: (B,) int32;
-    block_tables: (B,NB) int32 → (B,H,dh).
+    """q: (B,H,dh); k_pool,v_pool: (L,P,KV,block,dh); layer: () int32 the
+    pool layer to read; pos: (B,) int32; block_tables: (B,NB) int32 →
+    (B,H,dh).
 
-    Grid = (batch, kv_heads, ⌈NB / blocks_per_step⌉). ``pos`` and the
-    block table are scalar-prefetch operands: the K/V index maps pick the
-    physical block out of the pool, so the gather happens in the DMA
+    Grid = (batch, kv_heads, ⌈NB / blocks_per_step⌉). ``pos``, the block
+    table and ``layer`` are scalar-prefetch operands: the K/V index maps
+    pick the layer's physical block out of the whole pool, so neither the
+    layer slice nor the gather is ever materialized — it happens in the DMA
     engine, not the kernel body — amortized over ``blocks_per_step``
     logical blocks per grid step (each sub-tile is its own operand with
     its own index map). Windowless maps clamp the fetched logical index to
@@ -486,7 +496,7 @@ def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
     stays live once wrapped, so only the NB bound is clamped).
     """
     B, H, dh = q.shape
-    P, KV, block = k_pool.shape[0], k_pool.shape[1], k_pool.shape[2]
+    KV, block = k_pool.shape[2], k_pool.shape[3]
     NB = block_tables.shape[1]
     assert H % KV == 0
     group = H // KV
@@ -497,36 +507,38 @@ def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
 
     def kv_spec(j):
         if window <= 0:
-            def imap(b, h, kc, pos_r, bt_r):
+            def imap(b, h, kc, pos_r, bt_r, l_r):
                 # repro: bounds bt_r holds pool block ids < P (the
-                # pool's leading dim) — allocator invariant; ki is
-                # clamped to NB - 1, so bt_r[b, ki] stays in-table
+                # pool's block dim) — allocator invariant; ki is clamped
+                # to NB - 1, so bt_r[b, ki] stays in-table
+                # repro: bounds l_r[0] is the layer loop's scanned index
                 ki = jnp.minimum(jnp.minimum(kc * bps + j,
                                              pos_r[b] // block), NB - 1)
-                return (bt_r[b, ki], h, 0, 0)
+                return (l_r[0], bt_r[b, ki], h, 0, 0)
         else:
-            def imap(b, h, kc, pos_r, bt_r):
+            def imap(b, h, kc, pos_r, bt_r, l_r):
                 # repro: bounds bt_r holds pool block ids < P (the
-                # pool's leading dim) — allocator invariant; ki is
-                # clamped to NB - 1, so bt_r[b, ki] stays in-table
+                # pool's block dim) — allocator invariant; ki is clamped
+                # to NB - 1, so bt_r[b, ki] stays in-table
+                # repro: bounds l_r[0] is the layer loop's scanned index
                 ki = jnp.minimum(kc * bps + j, NB - 1)
-                return (bt_r[b, ki], h, 0, 0)
-        return pl.BlockSpec((1, 1, block, dh), imap)
+                return (l_r[0], bt_r[b, ki], h, 0, 0)
+        return pl.BlockSpec((pl.Squeezed(), 1, 1, block, dh), imap)
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale,
                                block=block, window=window, s_log=NB * block,
                                bps=bps, nb=NB)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                        # pos, block_tables
+        num_scalar_prefetch=3,                # pos, block_tables, layer
         grid=(B, KV, nkc),
         in_specs=[
             pl.BlockSpec((1, 1, group, dh),
-                         lambda b, h, kc, pos_r, bt_r: (b, h, 0, 0)),  # q
+                         lambda b, h, kc, *_: (b, h, 0, 0)),           # q
             *[kv_spec(j) for j in range(bps)],                         # k
             *[kv_spec(j) for j in range(bps)],                         # v
         ],
         out_specs=pl.BlockSpec((1, 1, group, dh),
-                               lambda b, h, kc, pos_r, bt_r: (b, h, 0, 0)),
+                               lambda b, h, kc, *_: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, 1), jnp.float32),
             pltpu.VMEM((group, 1), jnp.float32),
@@ -538,6 +550,7 @@ def paged_decode_attention(q: Array, k_pool: Array, v_pool: Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, group, dh), q.dtype),
         interpret=interpret,
-    )(pos.astype(jnp.int32), block_tables.astype(jnp.int32), qg,
+    )(pos.astype(jnp.int32), block_tables.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qg,
       *([k_pool] * bps), *([v_pool] * bps))
     return out.reshape(B, H, dh)

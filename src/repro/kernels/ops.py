@@ -72,27 +72,27 @@ def decode_attention(q, k, v, pos, *, window: int = 0, block_k: int = 256):
 
 
 @functools.partial(jax.jit, static_argnames=("window", "blocks_per_step"))
-def paged_decode_attention(q, k_pool, v_pool, pos, block_tables, *,
+def paged_decode_attention(q, k_pool, v_pool, layer, pos, block_tables, *,
                            window: int = 0, blocks_per_step: int = 1):
-    return _decode.paged_decode_attention(q, k_pool, v_pool, pos,
+    return _decode.paged_decode_attention(q, k_pool, v_pool, layer, pos,
                                           block_tables, window=window,
                                           blocks_per_step=blocks_per_step,
                                           interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("blocks_per_step",))
-def paged_verify_attention(q, k_pool, v_pool, pos, block_tables, *,
+def paged_verify_attention(q, k_pool, v_pool, layer, pos, block_tables, *,
                            blocks_per_step: int = 1):
-    return _decode.paged_verify_attention(q, k_pool, v_pool, pos,
+    return _decode.paged_verify_attention(q, k_pool, v_pool, layer, pos,
                                           block_tables,
                                           blocks_per_step=blocks_per_step,
                                           interpret=_interpret())
 
 
 @functools.partial(jax.jit, static_argnames=("blocks_per_step",))
-def chunk_prefill_attention(q, k_pool, v_pool, start, block_table, *,
+def chunk_prefill_attention(q, k_pool, v_pool, layer, start, block_table, *,
                             blocks_per_step: int = 1):
-    return _decode.chunk_prefill_attention(q, k_pool, v_pool, start,
+    return _decode.chunk_prefill_attention(q, k_pool, v_pool, layer, start,
                                            block_table,
                                            blocks_per_step=blocks_per_step,
                                            interpret=_interpret())

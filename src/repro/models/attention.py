@@ -176,14 +176,74 @@ def decode_attention(params, x: Array, cfg, cache: Tuple[Array, Array],
     return proj, (k_cache, v_cache)
 
 
+def _write_rows(pool: Array, layer, blk: Array, off: Array,
+                rows: Array) -> Array:
+    """Write ``rows[i]`` (KV, dh) at ``(layer, blk[i], :, off[i], :)`` of a
+    head-major (L, P, KV, block, dh) pool: one ``dynamic_update_slice`` per
+    row, in the pool's own layout, so XLA updates the carried pool in
+    place (a batched ``.at[].set`` scatter re-lays the pool out token-major
+    and back around every write)."""
+    rows = rows.astype(pool.dtype)[:, None, None, :, None, :]
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.int32(0)
+    for i in range(rows.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[i], (layer, blk[i].astype(jnp.int32), zero,
+                            off[i].astype(jnp.int32), zero))
+    return pool
+
+
+def _write_span(pool: Array, layer, block_table: Array, start: Array,
+                length: Array, rows: Array) -> Array:
+    """Write a chunk's rows (C, KV, dh) at logical positions ``start + c``
+    of one slot's block table (NB,), a whole (block, dh) tile at a time
+    with one in-place ``dynamic_update_slice`` each. A tile holding no row
+    below ``length`` (the padded tail, or past the table) goes to scratch
+    block 0, so padded rows never land in a block the slot does not own.
+
+    With ``C`` a multiple of the block size every chunk starts on a block
+    boundary (a prompt's chunks start at its cached prefix, whole blocks,
+    plus whole chunks), so the span is exactly ``C / block`` tiles and
+    each is written from the rows alone; the padded rows of the prompt's
+    last block land at positions no key is read from before decode
+    writes them. Otherwise the span touches at most ⌈C/block⌉ + 1 blocks
+    and each tile is read, merged with the rows that fall in it, and
+    written back. (The read is what the aligned case avoids: under the
+    mixture's vmap, XLA re-lays the whole stacked pool out for it.)"""
+    C = rows.shape[0]
+    _, _, KV, bs, dh = pool.shape
+    NB = block_table.shape[0]
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.int32(0)
+    rows = jnp.swapaxes(rows.astype(pool.dtype), 0, 1)        # (KV, C, dh)
+    aligned = C % bs == 0
+    offs = jnp.arange(bs)
+    for j in range(C // bs if aligned else -(-C // bs) + 1):
+        lb = start // bs + j
+        c = lb * bs + offs - start                     # chunk row per offset
+        live = (c >= 0) & (c < length) & (lb < NB)
+        blk = jnp.where(jnp.any(live),
+                        block_table[jnp.clip(lb, 0, NB - 1)], 0)
+        idx = (layer, blk.astype(jnp.int32), zero, zero, zero)
+        if aligned:
+            tile = rows[:, j * bs:(j + 1) * bs]
+        else:
+            old = jax.lax.dynamic_slice(pool, idx, (1, 1, KV, bs, dh))[0, 0]
+            new = jnp.take(rows, jnp.clip(c, 0, C - 1), axis=1)
+            tile = jnp.where(live[None, :, None], new, old)
+        pool = jax.lax.dynamic_update_slice(pool, tile[None, None], idx)
+    return pool
+
+
 def paged_decode_attention(params, x: Array, cfg,
-                           pool: Tuple[Array, Array], pos: Array,
+                           pool: Tuple[Array, Array], layer, pos: Array,
                            block_tables: Array, *,
                            use_kernel: bool = False, rope: bool = True):
     """One-token decode against a PAGED KV cache. x: (B, 1, D); pool K/V:
-    (P, KV, block, dh) shared block pool; pos: (B,) current positions;
-    block_tables: (B, NB) logical-block → physical-block map per slot.
-    Returns (out (B, 1, D), new pool).
+    (L, P, KV, block, dh), the whole layer-stacked block pool; layer: this
+    layer's index into it (a traced scalar in the layer loop); pos: (B,)
+    current positions; block_tables: (B, NB) logical-block → physical-block
+    map per slot. Returns (out (B, 1, D), new pool).
 
     Logical capacity is NB·block per slot; with ``cfg.sliding_window > 0``
     the slot's logical span is addressed as a ring of that size (the
@@ -194,32 +254,32 @@ def paged_decode_attention(params, x: Array, cfg,
     """
     B = x.shape[0]
     k_pool, v_pool = pool
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     NB = block_tables.shape[1]
     S_log = NB * bs
     with jax.named_scope("attn.qkv"):
         pos_b = jnp.broadcast_to(jnp.asarray(pos), (B,))
         q, k_new, v_new = _qkv(params, x, cfg, pos_b[:, None], rope=rope)
-    # scatter the new token's K/V into each slot's current block — physical
-    # blocks are uniquely owned, so the batched scatter never collides
+    # write the new token's K/V into each slot's current block — physical
+    # blocks are uniquely owned, so the row writes never collide
     # (inactive slots all write block 0 offset 0, the scratch block).
     with jax.named_scope("attn.kv_write"):
         r = pos_b % S_log if cfg.sliding_window > 0 else pos_b
         blk = jnp.take_along_axis(block_tables, (r // bs)[:, None],
                                   axis=1)[:, 0]
         off = r % bs
-        k_pool = k_pool.at[blk, :, off].set(k_new[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off].set(v_new[:, 0].astype(v_pool.dtype))
+        k_pool = _write_rows(k_pool, layer, blk, off, k_new[:, 0])
+        v_pool = _write_rows(v_pool, layer, blk, off, v_new[:, 0])
     with jax.named_scope("attn.kernel"):
         if use_kernel:
             from repro.kernels import ops as kops
-            out = kops.paged_decode_attention(q[:, 0], k_pool, v_pool, pos_b,
-                                              block_tables,
+            out = kops.paged_decode_attention(q[:, 0], k_pool, v_pool,
+                                              layer, pos_b, block_tables,
                                               window=cfg.sliding_window)
             out = out[:, None]
         else:
-            kf = gather_pages(k_pool, block_tables)
-            vf = gather_pages(v_pool, block_tables)
+            kf = gather_pages(k_pool, layer, block_tables)
+            vf = gather_pages(v_pool, layer, block_tables)
             idx = jnp.arange(S_log)[None, :]
             if cfg.sliding_window > 0:
                 valid = (idx <= pos_b[:, None]) | (pos_b[:, None] >= S_log)
@@ -233,33 +293,34 @@ def paged_decode_attention(params, x: Array, cfg,
 
 
 def paged_verify_attention(params, x: Array, cfg,
-                           pool: Tuple[Array, Array], pos: Array,
+                           pool: Tuple[Array, Array], layer, pos: Array,
                            block_tables: Array, *,
                            use_kernel: bool = False, rope: bool = True):
     """Speculative multi-token verify against a PAGED KV cache.
 
     x: (B, L, D) — row ℓ of slot b is the candidate token sitting at
     absolute position ``pos[b] + ℓ`` (row 0 is the slot's committed next
-    token, rows 1..L-1 are draft tokens); pool K/V: (P, KV, block, dh);
-    pos: (B,) each slot's current write position; block_tables: (B, NB).
-    Returns (out (B, L, D), new pool).
+    token, rows 1..L-1 are draft tokens); pool K/V: (layers, P, KV, block,
+    dh); layer: this layer's index into the pool; pos: (B,) each slot's
+    current write position; block_tables: (B, NB). Returns (out (B, L, D),
+    new pool).
 
-    All L candidate K/V are scattered into the pool FIRST, then every row
+    All L candidate K/V are written into the pool FIRST, then every row
     attends under the span-causal rule ``key position ≤ pos + ℓ`` — the
     same single masking rule as chunked prefill, so a candidate sees the
     committed prefix plus the earlier candidates of its own span.
     Rejected-tail writes are rolled back by OVERWRITE: they sit at
     positions strictly greater than the post-accept position, the mask
     hides them from every later query, and the next span (or vanilla
-    step) re-scatters those offsets before anything attends there.
-    Positions past the table horizon scatter into the reserved scratch
+    step) re-writes those offsets before anything attends there.
+    Positions past the table horizon write into the reserved scratch
     block 0 (inactive slots — pos 0, zeroed tables — land there too).
     Sliding-window (ring) addressing is not supported — the scheduler
     only routes speculation-capable (windowless) models here.
     """
     B, L, D = x.shape
     k_pool, v_pool = pool
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     NB = block_tables.shape[1]
     S_log = NB * bs
     with jax.named_scope("attn.qkv"):
@@ -274,18 +335,18 @@ def paged_verify_attention(params, x: Array, cfg,
             safe, block_tables[rows, jnp.clip(flat_pos // bs, 0, NB - 1)],
             0)
         off = jnp.where(safe, flat_pos % bs, 0)
-        k_pool = k_pool.at[blk, :, off].set(
-            k_new.reshape(B * L, *k_new.shape[2:]).astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off].set(
-            v_new.reshape(B * L, *v_new.shape[2:]).astype(v_pool.dtype))
+        k_pool = _write_rows(k_pool, layer, blk, off,
+                             k_new.reshape(B * L, *k_new.shape[2:]))
+        v_pool = _write_rows(v_pool, layer, blk, off,
+                             v_new.reshape(B * L, *v_new.shape[2:]))
     with jax.named_scope("attn.kernel"):
         if use_kernel:
             from repro.kernels import ops as kops
-            out = kops.paged_verify_attention(q, k_pool, v_pool, pos_b,
-                                              block_tables)
+            out = kops.paged_verify_attention(q, k_pool, v_pool, layer,
+                                              pos_b, block_tables)
         else:
-            kf = gather_pages(k_pool, block_tables)
-            vf = gather_pages(v_pool, block_tables)
+            kf = gather_pages(k_pool, layer, block_tables)
+            vf = gather_pages(v_pool, layer, block_tables)
             idx = jnp.arange(S_log)[None, None, :]
             valid = idx <= positions[:, :, None]            # (B, L, S_log)
             out = gqa_sdpa(q, kf, vf, valid,
@@ -296,47 +357,46 @@ def paged_verify_attention(params, x: Array, cfg,
 
 
 def chunk_attention(params, x: Array, cfg, pool: Tuple[Array, Array],
-                    start: Array, length: Array, block_table: Array, *,
-                    use_kernel: bool = False):
+                    layer, start: Array, length: Array, block_table: Array,
+                    *, use_kernel: bool = False):
     """Chunked-prefill self-attention THROUGH the paged pool.
 
     x: (1, C, D) chunk hidden states whose row c sits at absolute position
-    ``start + c``; pool K/V: (P, KV, block, dh) shared block pool;
-    ``length``: () int32 valid rows in this chunk (a final partial chunk is
-    right-padded to C); block_table: (NB,) int32 — THIS request's logical →
-    physical block map. Returns (out (1, C, D), new pool).
+    ``start + c``; pool K/V: (L, P, KV, block, dh), the whole layer-stacked
+    block pool; layer: this layer's index into it; ``length``: () int32
+    valid rows in this chunk (a final partial chunk is right-padded to C);
+    block_table: (NB,) int32 — THIS request's logical → physical block map.
+    Returns (out (1, C, D), new pool).
 
-    The chunk's K/V are scattered into the pool *first*, so within-chunk
+    The chunk's K/V are written into the pool *first*, so within-chunk
     causality flows through the same block-table read as the prefix written
     by earlier chunks — one masking rule (key position ≤ query position)
-    covers both. Padded rows scatter into the reserved scratch block 0 and
-    their outputs are garbage the caller discards; padded keys sit at
-    positions no valid query can attend, so they never leak.
+    covers both. Padded rows land only in the slot's own last block, at
+    positions no key is read from before decode writes them, or in the
+    scratch block (``_write_span``); their outputs are garbage the caller
+    discards.
     """
     B, C, D = x.shape
     k_pool, v_pool = pool
-    bs = k_pool.shape[2]
+    bs = k_pool.shape[3]
     NB = block_table.shape[0]
     S_log = NB * bs
     with jax.named_scope("attn.qkv"):
-        offs = jnp.arange(C)
-        pos_c = start + offs                                 # (C,)
+        pos_c = start + jnp.arange(C)                        # (C,)
         q, k_new, v_new = _qkv(params, x, cfg, pos_c[None, :])
     with jax.named_scope("attn.kv_write"):
-        valid = offs < length
-        blk = jnp.where(valid,
-                        block_table[jnp.clip(pos_c // bs, 0, NB - 1)], 0)
-        off = jnp.where(valid, pos_c % bs, 0)
-        k_pool = k_pool.at[blk, :, off].set(k_new[0].astype(k_pool.dtype))
-        v_pool = v_pool.at[blk, :, off].set(v_new[0].astype(v_pool.dtype))
+        k_pool = _write_span(k_pool, layer, block_table, start, length,
+                             k_new[0])
+        v_pool = _write_span(v_pool, layer, block_table, start, length,
+                             v_new[0])
     with jax.named_scope("attn.kernel"):
         if use_kernel:
             from repro.kernels import ops as kops
-            out = kops.chunk_prefill_attention(q[0], k_pool, v_pool, start,
-                                               block_table)[None]
+            out = kops.chunk_prefill_attention(q[0], k_pool, v_pool, layer,
+                                               start, block_table)[None]
         else:
-            kf = gather_pages(k_pool, block_table)[None]
-            vf = gather_pages(v_pool, block_table)[None]
+            kf = gather_pages(k_pool, layer, block_table)[None]
+            vf = gather_pages(v_pool, layer, block_table)[None]
             mask = (jnp.arange(S_log)[None, :] <= pos_c[:, None])[None]
             out = gqa_sdpa(q, kf, vf, mask,
                            jnp.dtype(cfg.attn_softmax_dtype))

@@ -57,21 +57,31 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return jax.checkpoint(fn)
 
 
-def scan_layers(body, carry, xs, cfg: ModelConfig):
+def scan_layers(body, carry, xs, cfg: ModelConfig, *, indexed: bool = False):
     """``jax.lax.scan`` over a stacked layer dim — or, when ``cfg.unroll``
     is set (dry-run depth probes), an unrolled python loop producing
     straight-line HLO with identical semantics. Named scope ``layers``:
-    the loop's own slicing of ``xs`` (each layer's weights and cache) and
-    writing back of the stacked ``ys`` are attributed to it in a device
-    trace, the body's ops to their inner scopes."""
+    the loop's own slicing of ``xs`` (each layer's weights and direct
+    state) and writing back of the stacked ``ys`` are attributed to it in
+    a device trace, the body's ops to their inner scopes.
+
+    ``indexed`` calls ``body(carry, x, i)`` with the layer's index ``i``:
+    a scanned ``jnp.arange(L)`` element, a Python int when unrolled. The
+    paged serving steps use it to address the layer-stacked KV pool they
+    carry whole in ``carry`` (a pool in ``xs`` would be sliced out and
+    re-stacked every step)."""
     with jax.named_scope("layers"):
-        if not cfg.unroll:
-            return jax.lax.scan(body, carry, xs)
         L = jax.tree.leaves(xs)[0].shape[0]
+        if not cfg.unroll:
+            if not indexed:
+                return jax.lax.scan(body, carry, xs)
+            return jax.lax.scan(lambda c, xi: body(c, xi[0], xi[1]), carry,
+                                (xs, jnp.arange(L, dtype=jnp.int32)))
         ys = []
         for i in range(L):
             layer = jax.tree.map(lambda a, i=i: a[i], xs)
-            carry, y = body(carry, layer)
+            carry, y = body(carry, layer, i) if indexed \
+                else body(carry, layer)
             ys.append(y)
         if all(y is None for y in ys):
             return carry, None
@@ -119,9 +129,13 @@ class CacheSpec:
     def shifted(self, by: int = 1) -> "CacheSpec":
         """Spec for the same cache with ``by`` extra dims inserted before
         every batch axis (e.g. the stacked-expert K dim of the mixture
-        decode core, which sits after each leaf's scan dim). Memoized so
+        decode core, which leads each pool leaf and sits after every other
+        leaf's scan dim — before the batch axis either way). Memoized so
         repeat callers share one spec — and with it the jitted splice
-        functions below (a fresh spec would recompile them)."""
+        functions below (a fresh spec would recompile them).
+
+        Every splice below consumes (donates) its ``cache`` argument so
+        the pool is written in place; callers rebind the result."""
         memo = self.__dict__.setdefault("_shifted_memo", {})
         if by not in memo:
             paged = self.paged
@@ -148,7 +162,7 @@ class CacheSpec:
                 lambda full, row, ax: jax.lax.dynamic_update_slice_in_dim(
                     full, row.astype(full.dtype), slot, axis=ax),
                 cache, row_cache, self.batch_axes)
-        return jax.jit(insert_row)
+        return jax.jit(insert_row, donate_argnums=(0,))
 
     def insert_paged(self, cache, row_cache, slot: int, blocks: Array):
         """Splice a single-request contiguous prefill cache into the paged
@@ -192,7 +206,7 @@ class CacheSpec:
 
             seq = self.paged.seq_axes
             return jax.tree.map(one, cache, row_cache, self.batch_axes, seq)
-        return jax.jit(insert_paged)
+        return jax.jit(insert_paged, donate_argnums=(0,))
 
     def insert_direct(self, cache, carry, slot: int):
         """Write a chunked-prefill carry (single-request DIRECT-leaf decode
@@ -214,7 +228,7 @@ class CacheSpec:
                     full, row.astype(full.dtype), slot, axis=ax)
 
             return jax.tree.map(one, cache, carry, self.batch_axes, seq)
-        return jax.jit(insert_direct)
+        return jax.jit(insert_direct, donate_argnums=(0,))
 
     def take(self, cache, slot: int):
         """Read one slot's cache back out (batch extent 1 preserved)."""
@@ -259,20 +273,27 @@ class CacheSpec:
         positionally matching the payload's gather order — and direct
         leaves overwrite the resumed slot's row. ``slot``/``blocks`` need
         not match the ones swapped out; the block *table* mapping logical
-        to physical order is the caller's to rebuild."""
+        to physical order is the caller's to rebuild. Jitted (one trace
+        per block count, as resumes are rare) so it can consume its
+        ``cache`` in place."""
         assert self.paged is not None, "swap_in needs a paged spec"
-        blocks = jnp.asarray(blocks, jnp.int32)
+        return self._swap_in_jit(cache, payload, jnp.int32(slot),
+                                 jnp.asarray(blocks, jnp.int32))
 
-        def one(full, row, b_ax, s_ax):
-            row = jnp.asarray(row, full.dtype)
-            if s_ax < 0:
-                return jax.lax.dynamic_update_slice_in_dim(full, row, slot,
-                                                           axis=b_ax)
-            idx = (slice(None),) * b_ax + (blocks,)
-            return full.at[idx].set(row)
+    @cached_property
+    def _swap_in_jit(self):
+        def swap_in(cache, payload, slot, blocks):
+            def one(full, row, b_ax, s_ax):
+                row = jnp.asarray(row, full.dtype)
+                if s_ax < 0:
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        full, row, slot, axis=b_ax)
+                idx = (slice(None),) * b_ax + (blocks,)
+                return full.at[idx].set(row)
 
-        return jax.tree.map(one, cache, payload, self.batch_axes,
-                            self.paged.seq_axes)
+            return jax.tree.map(one, cache, payload, self.batch_axes,
+                                self.paged.seq_axes)
+        return jax.jit(swap_in, donate_argnums=(0,))
 
 
 @dataclass
@@ -810,30 +831,36 @@ class Model:
         blocks; recurrent / conv / cross-attention state flows through
         ``carry``. Returns (last_logits (1, V) — the greedy next-token
         distribution at the chunk's final valid position — new_carry,
-        new_cache). Padded rows are exact no-ops on carry and pool.
+        new_cache). Padded rows are exact no-ops on carry, and on the pool
+        write only positions past the prompt, which decode writes before
+        any query reads them. ``start`` is a multiple of the block size
+        whenever C is (a prompt's chunks start at its cached prefix, whole
+        blocks, plus whole chunks).
         """
         cfg = self.cfg
         C = x.shape[1]
 
         if cfg.family in ("dense", "vlm", "moe"):
-            def body(xh, layer_and_pool):
-                layer, pool = layer_and_pool
+            def body(c, layer, li):
+                xh, pool = c
                 return self._attn_mlp_layer(
                     layer, xh, lambda p, xn: attn.chunk_attention(
-                        p, xn, cfg, pool, start, length, block_table,
-                        use_kernel=use_kernel))
-            x, (ks, vs) = scan_layers(
-                body, x, (params["blocks"], (cache["k"], cache["v"])), cfg)
+                        p, xn, cfg, pool, li, start, length, block_table,
+                        use_kernel=use_kernel)), None
+            (x, (ks, vs)), _ = scan_layers(
+                body, (x, (cache["k"], cache["v"])), params["blocks"], cfg,
+                indexed=True)
             new_cache = {"k": ks, "v": vs}
             new_carry = carry
 
         elif cfg.family == "audio":
-            def body(xh, layer_and_c):
-                layer, (k, v, xk, xv) = layer_and_c
-                a, kv = attn.chunk_attention(
+            def body(c, layer_and_x, li):
+                xh, pool = c
+                layer, (xk, xv) = layer_and_x
+                a, pool = attn.chunk_attention(
                     layer["self_attn"],
                     rms_norm(xh, layer["ln1"], cfg.norm_eps),
-                    cfg, (k, v), start, length, block_table,
+                    cfg, pool, li, start, length, block_table,
                     use_kernel=use_kernel)
                 h = xh + a
                 h = h + attn.cross_attention(
@@ -841,11 +868,11 @@ class Model:
                     rms_norm(h, layer["ln2"], cfg.norm_eps), (xk, xv), cfg)
                 out = h + swiglu(layer["ffn"],
                                  rms_norm(h, layer["ln3"], cfg.norm_eps))
-                return out, kv
-            x, (ks, vs) = scan_layers(
-                body, x, (params["blocks"],
-                          (cache["k"], cache["v"], carry["xk"],
-                           carry["xv"])), cfg)
+                return (out, pool), None
+            (x, (ks, vs)), _ = scan_layers(
+                body, (x, (cache["k"], cache["v"])),
+                (params["blocks"], (carry["xk"], carry["xv"])), cfg,
+                indexed=True)
             new_cache = {"k": ks, "v": vs,
                          "xk": cache["xk"], "xv": cache["xv"]}
             new_carry = carry
@@ -889,8 +916,9 @@ class Model:
         elif cfg.family == "hybrid":
             shared = params["shared_attn"]
 
-            def body(xh, group_and_c):
-                group, (ssm_st, conv_st, k, v) = group_and_c
+            def body(c, group_and_st, gi):
+                xh, pool = c
+                group, (ssm_st, conv_st) = group_and_st
 
                 def m_body(h, mc):
                     m, st = mc
@@ -902,18 +930,18 @@ class Model:
                     m_body, xh,
                     ({"ln": group["m_ln"], "core": group["mamba"]},
                      (ssm_st, conv_st)), cfg)
-                a, kv = attn.chunk_attention(
+                a, pool = attn.chunk_attention(
                     shared["attn"], rms_norm(xh, shared["ln1"], cfg.norm_eps),
-                    cfg, (k, v), start, length, block_table,
+                    cfg, pool, gi, start, length, block_table,
                     use_kernel=use_kernel)
                 h = xh + a
                 out = h + swiglu(shared["ffn"],
                                  rms_norm(h, shared["ln2"], cfg.norm_eps))
-                return out, (ssm_st, conv_st) + kv
-            x, (ssm_s, conv_s, ks, vs) = scan_layers(
-                body, x, (params["blocks"],
-                          (carry["ssm"], carry["conv"],
-                           cache["k"], cache["v"])), cfg)
+                return (out, pool), (ssm_st, conv_st)
+            (x, (ks, vs)), (ssm_s, conv_s) = scan_layers(
+                body, (x, (cache["k"], cache["v"])),
+                (params["blocks"], (carry["ssm"], carry["conv"])), cfg,
+                indexed=True)
             new_carry = {"ssm": ssm_s, "conv": conv_s,
                          "k": carry["k"], "v": carry["v"]}
             new_cache = {"ssm": cache["ssm"], "conv": cache["conv"],
@@ -1086,9 +1114,10 @@ class Model:
         """One-token decode against the paged cache. tokens: (B,) int32;
         pos: (B,) int32 per-slot positions; block_tables: (B, NB) int32
         logical-block → physical-pool-block maps (one table per slot,
-        shared by every attention layer). Attention KV leaves gather /
-        scatter through the pool; recurrent and cross-attention leaves run
-        the direct path unchanged."""
+        shared by every attention layer). Attention KV leaves are written
+        in place in the layer-stacked pool the layer loop carries
+        (``_paged_layers``); recurrent and cross-attention leaves run the
+        direct path unchanged."""
         cfg = self.cfg
         if cfg.family == "ssm":       # no pageable leaves: direct path
             return self.decode_step(params, cache, tokens, pos,
@@ -1096,24 +1125,39 @@ class Model:
         with jax.named_scope("embed"):
             x = embed(params["embed"], tokens[:, None], cfg.cdtype)  # (B,1,D)
 
+        def attend(p, xn, pool, li):
+            return attn.paged_decode_attention(
+                p, xn, cfg, pool, li, pos, block_tables,
+                use_kernel=use_kernel)
+        logits, cache = self._paged_layers(params, cache, x, attend)
+        return logits[:, 0], cache
+
+    def _paged_layers(self, params, cache, x: Array, attend):
+        """The layer loop of the paged serving steps (decode and verify):
+        ``attend(attn_params, normed_x, pool, layer)`` → ``(out, pool)``
+        is the layer's self-attention against the layer-stacked pool
+        (``(k, v)``), which rides the scan carry whole and is updated in
+        place; direct state (audio's cross K/V, hybrid's conv/SSM) is
+        scanned as before. Returns (logits at every row, new cache)."""
+        cfg = self.cfg
+        pool = (cache["k"], cache["v"])
         if cfg.family in ("dense", "vlm", "moe"):
-            def body(x, layer_and_cache):
-                layer, kv = layer_and_cache
+            def body(c, layer, li):
+                x, pool = c
                 return self._attn_mlp_layer(
-                    layer, x, lambda p, xn: attn.paged_decode_attention(
-                        p, xn, cfg, kv, pos, block_tables,
-                        use_kernel=use_kernel))
-            x, (ks, vs) = scan_layers(
-                body, x, (params["blocks"], (cache["k"], cache["v"])), cfg)
+                    layer, x, lambda p, xn: attend(p, xn, pool, li)), None
+            (x, (ks, vs)), _ = scan_layers(body, (x, pool),
+                                           params["blocks"], cfg,
+                                           indexed=True)
             new_cache = {"k": ks, "v": vs}
 
         elif cfg.family == "audio":
-            def body(x, layer_and_cache):
-                layer, (k, v, xk, xv) = layer_and_cache
-                a, kv = attn.paged_decode_attention(
-                    layer["self_attn"], rms_norm(x, layer["ln1"],
-                                                 cfg.norm_eps),
-                    cfg, (k, v), pos, block_tables, use_kernel=use_kernel)
+            def body(c, layer_and_x, li):
+                x, pool = c
+                layer, (xk, xv) = layer_and_x
+                a, pool = attend(layer["self_attn"],
+                                 rms_norm(x, layer["ln1"], cfg.norm_eps),
+                                 pool, li)
                 h = x + a
                 h = h + attn.cross_attention(
                     layer["cross_attn"], rms_norm(h, layer["ln2"],
@@ -1121,18 +1165,21 @@ class Model:
                     (xk, xv), cfg)
                 out = h + swiglu(layer["ffn"],
                                  rms_norm(h, layer["ln3"], cfg.norm_eps))
-                return out, kv + (xk, xv)
-            x, (ks, vs, xks, xvs) = scan_layers(
-                body, x, (params["blocks"],
-                          (cache["k"], cache["v"], cache["xk"],
-                           cache["xv"])), cfg)
-            new_cache = {"k": ks, "v": vs, "xk": xks, "xv": xvs}
+                return (out, pool), None
+            (x, (ks, vs)), _ = scan_layers(
+                body, (x, pool), (params["blocks"],
+                                  (cache["xk"], cache["xv"])), cfg,
+                indexed=True)
+            new_cache = {"k": ks, "v": vs, "xk": cache["xk"],
+                         "xv": cache["xv"]}
 
         elif cfg.family == "hybrid":
             shared = params["shared_attn"]
 
-            def body(x, group_and_cache):
-                group, (ssm_st, conv_st, k, v) = group_and_cache
+            def body(c, group_and_st, gi):
+                x, pool = c
+                group, (ssm_st, conv_st) = group_and_st
+
                 def m_body(h, mc):
                     m, st = mc
                     y, st = ssm_lib.mamba2_step(
@@ -1142,23 +1189,22 @@ class Model:
                 x, (ssm_st, conv_st) = scan_layers(
                     m_body, x, ({"ln": group["m_ln"], "core": group["mamba"]},
                                 (ssm_st, conv_st)), cfg)
-                a, kv = attn.paged_decode_attention(
-                    shared["attn"], rms_norm(x, shared["ln1"], cfg.norm_eps),
-                    cfg, (k, v), pos, block_tables, use_kernel=use_kernel)
+                a, pool = attend(shared["attn"],
+                                 rms_norm(x, shared["ln1"], cfg.norm_eps),
+                                 pool, gi)
                 h = x + a
                 out = h + swiglu(shared["ffn"],
                                  rms_norm(h, shared["ln2"], cfg.norm_eps))
-                return out, (ssm_st, conv_st) + kv
-            x, (ssm_s, conv_s, ks, vs) = scan_layers(
-                body, x, (params["blocks"],
-                          (cache["ssm"], cache["conv"],
-                           cache["k"], cache["v"])), cfg)
+                return (out, pool), (ssm_st, conv_st)
+            (x, (ks, vs)), (ssm_s, conv_s) = scan_layers(
+                body, (x, pool), (params["blocks"],
+                                  (cache["ssm"], cache["conv"])), cfg,
+                indexed=True)
             new_cache = {"ssm": ssm_s, "conv": conv_s, "k": ks, "v": vs}
         else:
             raise ValueError(cfg.family)
 
-        logits = self._lm_head(params, x)
-        return logits[:, 0], new_cache
+        return self._lm_head(params, x), new_cache
 
     def verify_step_paged(self, params, cache, tokens: Array, pos: Array,
                           block_tables: Array, *, use_kernel: bool = False):
@@ -1183,42 +1229,11 @@ class Model:
         with jax.named_scope("embed"):
             x = embed(params["embed"], tokens, cfg.cdtype)       # (B,L,D)
 
-        if cfg.family in ("dense", "vlm", "moe"):
-            def body(x, layer_and_cache):
-                layer, kv = layer_and_cache
-                return self._attn_mlp_layer(
-                    layer, x, lambda p, xn: attn.paged_verify_attention(
-                        p, xn, cfg, kv, pos, block_tables,
-                        use_kernel=use_kernel))
-            x, (ks, vs) = scan_layers(
-                body, x, (params["blocks"], (cache["k"], cache["v"])), cfg)
-            new_cache = {"k": ks, "v": vs}
-
-        elif cfg.family == "audio":
-            def body(x, layer_and_cache):
-                layer, (k, v, xk, xv) = layer_and_cache
-                a, kv = attn.paged_verify_attention(
-                    layer["self_attn"], rms_norm(x, layer["ln1"],
-                                                 cfg.norm_eps),
-                    cfg, (k, v), pos, block_tables, use_kernel=use_kernel)
-                h = x + a
-                h = h + attn.cross_attention(
-                    layer["cross_attn"], rms_norm(h, layer["ln2"],
-                                                  cfg.norm_eps),
-                    (xk, xv), cfg)
-                out = h + swiglu(layer["ffn"],
-                                 rms_norm(h, layer["ln3"], cfg.norm_eps))
-                return out, kv + (xk, xv)
-            x, (ks, vs, xks, xvs) = scan_layers(
-                body, x, (params["blocks"],
-                          (cache["k"], cache["v"], cache["xk"],
-                           cache["xv"])), cfg)
-            new_cache = {"k": ks, "v": vs, "xk": xks, "xv": xvs}
-        else:
-            raise ValueError(cfg.family)
-
-        logits = self._lm_head(params, x)
-        return logits, new_cache
+        def attend(p, xn, pool, li):
+            return attn.paged_verify_attention(
+                p, xn, cfg, pool, li, pos, block_tables,
+                use_kernel=use_kernel)
+        return self._paged_layers(params, cache, x, attend)
 
     def fused_verify_step(self, params, cache, state, drafts: Array, *,
                           cache_len: int, use_kernel: bool = False):

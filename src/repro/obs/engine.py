@@ -198,6 +198,13 @@ class EngineObs:
                                    "free physical KV blocks in the pool")
         self.pool_total_g = r.gauge("serve_pool_blocks",
                                     "physical KV blocks in the pool")
+        pool_help = ("step-program calls on a paged pool, by whether the "
+                     "donated pool was consumed (kept: updated in place) "
+                     "or survived the call (copied)")
+        self.pool_kept = r.counter("serve_pool_inplace_total", pool_help,
+                                   labels={"outcome": "kept"})
+        self.pool_copied = r.counter("serve_pool_inplace_total", pool_help,
+                                     labels={"outcome": "copied"})
         # -- request latency (histograms) --------------------------------
         self.queued_s = r.histogram(
             "serve_request_queued_seconds",

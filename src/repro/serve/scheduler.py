@@ -130,7 +130,8 @@ import numpy as np
 from repro.analysis.sanitizer import PoolSanitizer
 from repro.core.ensemble import (PROB_FLOOR, make_stacked_chunk_fns,
                                  make_stacked_fused, make_stacked_serving,
-                                 make_stacked_verify, mix_expert_logits)
+                                 make_stacked_verify, mix_expert_logits,
+                                 stacked_cache_axes)
 from repro.models.model import Model
 from repro.obs import metrics as _obs_metrics
 from repro.obs.engine import NULL_SPAN, EngineObs
@@ -1643,13 +1644,32 @@ class _SlotTable:
     def _dispatch(self, run, *args, inner=NULL_SPAN):
         """``run(*args)``, the step's one jitted dispatch, in a
         ``dispatch`` span (around ``inner``, a chunk's own span); the
-        always-on histogram times the call alone."""
+        always-on histogram times the call alone.
+
+        Every step program consumes (donates) the cache it is given, so
+        XLA updates the pool in place. A donation XLA cannot use only
+        warns and brings back a whole copy of the pool, so the pool arrays
+        passed in are checked afterwards: consumed counts
+        ``serve_pool_inplace_total{outcome="kept"}``, still alive
+        ``{outcome="copied"}``."""
+        pool = self._pool_leaves()
         with self.obs.span("dispatch"), inner:
             t0 = time.perf_counter()
             out = run(*args)
             t1 = time.perf_counter()
         self.obs.dispatch_s.observe(t1 - t0)
+        if pool:
+            kept = all(a.is_deleted() for a in pool)
+            (self.obs.pool_kept if kept else self.obs.pool_copied).inc()
         return out
+
+    def _pool_leaves(self) -> List[Array]:
+        """The cache's paged pool arrays (none without a paged layout)."""
+        if not self.paged:
+            return []
+        return [a for a, s in zip(jax.tree.leaves(self.cache),
+                                  jax.tree.leaves(self.spec.paged.seq_axes))
+                if s >= 0]
 
     def _read_back(self, arrays):
         """The step's one ``jax.device_get``, in a ``device_get`` span and
@@ -2215,7 +2235,8 @@ def make_chunk_fns(model: Model, cache_len: int, chunk: int, *,
     the chunk writes land in its own reserved blocks, and the chunk's
     recurrent state flows through its carry — the lockstep decode's
     garbage updates to the mid-prefill slot's cache rows are overwritten by
-    ``insert_direct`` at the transition."""
+    ``insert_direct`` at the transition. Both steps consume (donate) their
+    cache argument, like every step program (``make_fused_fns``)."""
     def top1_prep(p, b):
         x = model.embed_prompt(p, b)                    # (1, W, D)
         return x, model.init_chunk_carry(p, b, cache_len)
@@ -2240,8 +2261,9 @@ def make_chunk_fns(model: Model, cache_len: int, chunk: int, *,
             c_logits, carry, c = model.prefill_chunk(
                 p, c, carry, xc, start, ln, cbt, use_kernel=use_kernel)
             return d_logits, c_logits, carry, c
-    return (jax.jit(top1_prep), jax.jit(top1_decode_chunk_logits),
-            jax.jit(top1_chunk_logits))
+    return (jax.jit(top1_prep),
+            jax.jit(top1_decode_chunk_logits, donate_argnums=(1,)),
+            jax.jit(top1_chunk_logits, donate_argnums=(1,)))
 
 
 def make_serve_fns(model: Model, cache_len: int, *, use_kernel: bool = False,
@@ -2249,7 +2271,8 @@ def make_serve_fns(model: Model, cache_len: int, *, use_kernel: bool = False,
     """The jitted (prefill, decode) pair one SlotServer runs on. Params are
     an explicit argument, so pods serving different experts of the same
     model SHARE one pair (one trace/compile instead of K). With ``paged``
-    the decode fn takes the per-slot block tables as its last argument."""
+    the decode fn takes the per-slot block tables as its last argument.
+    The decode consumes (donates) its cache argument."""
     def top1_prefill(p, b):
         return model.prefill(p, b, cache_len, use_kernel=use_kernel)
 
@@ -2260,7 +2283,8 @@ def make_serve_fns(model: Model, cache_len: int, *, use_kernel: bool = False,
     else:
         def top1_decode_logits(p, c, t, pos):
             return model.decode_step(p, c, t, pos, use_kernel=use_kernel)
-    return jax.jit(top1_prefill), jax.jit(top1_decode_logits)
+    return (jax.jit(top1_prefill),
+            jax.jit(top1_decode_logits, donate_argnums=(1,)))
 
 
 def make_fused_fns(model: Model, cache_len: int, chunk: int = 0, *,
@@ -2281,13 +2305,18 @@ def make_fused_fns(model: Model, cache_len: int, chunk: int = 0, *,
     * ``top1_chunk_only(params, cache, carry, xc, start, length, cbt, temp,
       top_k, seed)`` → ``(first, carry, cache)`` — a chunk with nothing
       decoding. The last two are None when ``chunk == 0``.
+
+    Each consumes (donates) its cache argument: with the pool riding the
+    layer loop's carry and written in place (``Model._paged_layers``), the
+    step then touches only the rows it writes. Callers rebind the cache
+    they get back and never read the one they passed.
     """
     def top1_fused_decode(p, c, st):
         return model.fused_decode_step(p, c, st, cache_len=cache_len,
                                        use_kernel=use_kernel, paged=paged)
 
     if chunk <= 0:
-        return jax.jit(top1_fused_decode), None, None
+        return jax.jit(top1_fused_decode, donate_argnums=(1,)), None, None
 
     def top1_fused_decode_chunk(p, c, st, carry, xc, start, ln, cbt, temp,
                                 top_k, seed):
@@ -2305,8 +2334,9 @@ def make_fused_fns(model: Model, cache_len: int, chunk: int = 0, *,
                                               cbt, use_kernel=use_kernel)
         return pick_first(c_out, temp, top_k, seed), carry, c
 
-    return (jax.jit(top1_fused_decode), jax.jit(top1_fused_decode_chunk),
-            jax.jit(top1_chunk_only))
+    return (jax.jit(top1_fused_decode, donate_argnums=(1,)),
+            jax.jit(top1_fused_decode_chunk, donate_argnums=(1,)),
+            jax.jit(top1_chunk_only, donate_argnums=(1,)))
 
 
 def make_verify_fns(model: Model, cache_len: int, *,
@@ -2317,12 +2347,13 @@ def make_verify_fns(model: Model, cache_len: int, *,
     ``(cache, state, toks, n_emit, done)`` — the span forward over
     ``[committed token, drafts]`` plus the accept/reject epilogue in one
     dispatch (``Model.fused_verify_step``). Traces once per drafts width,
-    which is fixed at ``spec_len - 1`` for an engine's lifetime."""
+    which is fixed at ``spec_len - 1`` for an engine's lifetime. Consumes
+    (donates) its cache argument."""
     def top1_fused_verify(p, c, st, drafts):
         return model.fused_verify_step(p, c, st, drafts,
                                        cache_len=cache_len,
                                        use_kernel=use_kernel)
-    return jax.jit(top1_fused_verify)
+    return jax.jit(top1_fused_verify, donate_argnums=(1,))
 
 
 class SlotServer(_SlotTable):
@@ -2469,26 +2500,24 @@ class SlotServer(_SlotTable):
         if not dec and not do_chunk:
             return []
         self._step_kind = "unfused"
+        run = self._dispatch
         if do_chunk:
             slot, xc, start, length, cbt = self._chunk_args()
             if not dec:
-                c_out, carry, self.cache = self._chunk_only(
-                    self.params, self.cache, self.prefill_carry[slot], xc,
-                    start, length, cbt)
+                c_out, carry, self.cache = run(
+                    self._chunk_only, self.params, self.cache,
+                    self.prefill_carry[slot], xc, start, length, cbt)
                 self.prefill_carry[slot] = carry
                 return self._after_chunk(slot, length, c_out)
             self._grow_active()
-            if self.paged:
-                d_logits, c_out, carry, self.cache = self._fused(
-                    self.params, self.cache, jnp.asarray(self.last_tok),
-                    jnp.asarray(self.pos),
-                    jnp.asarray(self._decode_tables()[:, :self._nb_live()]),
-                    self.prefill_carry[slot], xc, start, length, cbt)
-            else:
-                d_logits, c_out, carry, self.cache = self._fused(
-                    self.params, self.cache, jnp.asarray(self.last_tok),
-                    jnp.asarray(self.pos), self.prefill_carry[slot], xc,
-                    start, length, cbt)
+            # the decode's block tables, on a paged cache
+            tables = (jnp.asarray(
+                self._decode_tables()[:, :self._nb_live()]),) \
+                if self.paged else ()
+            d_logits, c_out, carry, self.cache = run(
+                self._fused, self.params, self.cache,
+                jnp.asarray(self.last_tok), jnp.asarray(self.pos), *tables,
+                self.prefill_carry[slot], xc, start, length, cbt)
             self.prefill_carry[slot] = carry
             nxt = self._next_tokens(d_logits)
             retired = self._advance(nxt)
@@ -2496,14 +2525,14 @@ class SlotServer(_SlotTable):
             return retired
         if self.paged:
             self._grow_active()
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(self.last_tok),
-                jnp.asarray(self.pos),
+            logits, self.cache = run(
+                self._decode, self.params, self.cache,
+                jnp.asarray(self.last_tok), jnp.asarray(self.pos),
                 jnp.asarray(self._decode_tables()[:, :self._nb_live()]))
         else:
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(self.last_tok),
-                jnp.asarray(self.pos))
+            logits, self.cache = run(
+                self._decode, self.params, self.cache,
+                jnp.asarray(self.last_tok), jnp.asarray(self.pos))
         return self._advance(self._next_tokens(logits))
 
 
@@ -2578,8 +2607,9 @@ class MixtureSlotServer(_SlotTable):
                     c_probs, carry, c = chunk_all(sp, c, carry, xc, start,
                                                   ln, cbt, w_row)
                     return probs, c_probs, carry, c
-            self._fused_mix = jax.jit(mixture_decode_chunk_probs)
-            self._chunk_only_mix = jax.jit(chunk_all)
+            self._fused_mix = jax.jit(mixture_decode_chunk_probs,
+                                      donate_argnums=(1,))
+            self._chunk_only_mix = jax.jit(chunk_all, donate_argnums=(1,))
         self.fused = config.fused_step
         if self.fused:
             self._fstep, self._fstep_chunk, self._fchunk_only = \
@@ -2592,15 +2622,17 @@ class MixtureSlotServer(_SlotTable):
                 model, param_axes, cache_len, config.spec_len,
                 use_kernel=use_kernel,
                 expert_draft=config.speculative == "expert"))
-        # expert (K) dim at axis 1, AFTER each leaf's scan dim — the layout
-        # the vmapped scanned decode consumes without per-step transposes
+        # expert (K) dim where the vmapped step consumes it without a
+        # transpose (``stacked_cache_axes``): leading each paged pool
+        # leaf, after the scan dim of every leaf the layer loop scans
         shapes = model.paged_cache_shapes(
             n_slots, self.allocator.n_blocks, page_block, cache_len) \
             if self.paged else model.cache_shapes(n_slots, cache_len)
         self.cache = jax.tree.map(
-            lambda s: jnp.zeros(s.shape[:1] + (self.K,) + s.shape[1:],
-                                s.dtype), shapes)
-        # batch/seq axes move by 1 under the K dim
+            lambda s, ax: jnp.zeros(s.shape[:ax] + (self.K,) + s.shape[ax:],
+                                    s.dtype),
+            shapes, stacked_cache_axes(model, self.paged))
+        # batch/seq axes move by 1 under the K dim (it lies before them)
         self.spec = model.cache_spec(page_block).shifted(1)
         self.weights = np.zeros((n_slots, self.K), dtype=np.float32)
         self._mix = jax.jit(mix_expert_logits)
@@ -2703,27 +2735,26 @@ class MixtureSlotServer(_SlotTable):
         if not dec and not do_chunk:
             return []
         self._step_kind = "unfused"
+        run = self._dispatch
         if do_chunk:
             slot, xc, start, length, cbt = self._chunk_args()
             w_row = jnp.asarray(self.weights[slot:slot + 1])
             if not dec:
-                c_out, carry, self.cache = self._chunk_only_mix(
-                    self.stacked, self.cache, self.prefill_carry[slot], xc,
-                    start, length, cbt, w_row)
+                c_out, carry, self.cache = run(
+                    self._chunk_only_mix, self.stacked, self.cache,
+                    self.prefill_carry[slot], xc, start, length, cbt, w_row)
                 self.prefill_carry[slot] = carry
                 return self._after_chunk(slot, length, c_out)
             self._grow_active()
-            if self.paged:
-                probs, c_out, carry, self.cache = self._fused_mix(
-                    self.stacked, self.cache, jnp.asarray(self.last_tok),
-                    jnp.asarray(self.pos), jnp.asarray(self.weights),
-                    jnp.asarray(self._decode_tables()[:, :self._nb_live()]),
-                    self.prefill_carry[slot], xc, start, length, cbt, w_row)
-            else:
-                probs, c_out, carry, self.cache = self._fused_mix(
-                    self.stacked, self.cache, jnp.asarray(self.last_tok),
-                    jnp.asarray(self.pos), jnp.asarray(self.weights),
-                    self.prefill_carry[slot], xc, start, length, cbt, w_row)
+            # the decode's block tables, on a paged cache
+            tables = (jnp.asarray(
+                self._decode_tables()[:, :self._nb_live()]),) \
+                if self.paged else ()
+            probs, c_out, carry, self.cache = run(
+                self._fused_mix, self.stacked, self.cache,
+                jnp.asarray(self.last_tok), jnp.asarray(self.pos),
+                jnp.asarray(self.weights), *tables,
+                self.prefill_carry[slot], xc, start, length, cbt, w_row)
             self.prefill_carry[slot] = carry
             retired = self._advance(self._next_tokens(probs,
                                                       from_probs=True))
@@ -2731,14 +2762,16 @@ class MixtureSlotServer(_SlotTable):
             return retired
         if self.paged:
             self._grow_active()
-            probs, self.cache = self._mix_decode(
-                self.stacked, self.cache, jnp.asarray(self.last_tok),
-                jnp.asarray(self.pos), jnp.asarray(self.weights),
+            probs, self.cache = run(
+                self._mix_decode, self.stacked, self.cache,
+                jnp.asarray(self.last_tok), jnp.asarray(self.pos),
+                jnp.asarray(self.weights),
                 jnp.asarray(self._decode_tables()[:, :self._nb_live()]))
         else:
-            probs, self.cache = self._mix_decode(
-                self.stacked, self.cache, jnp.asarray(self.last_tok),
-                jnp.asarray(self.pos), jnp.asarray(self.weights))
+            probs, self.cache = run(
+                self._mix_decode, self.stacked, self.cache,
+                jnp.asarray(self.last_tok), jnp.asarray(self.pos),
+                jnp.asarray(self.weights))
         return self._advance(self._next_tokens(probs, from_probs=True))
 
 

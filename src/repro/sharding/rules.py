@@ -90,31 +90,40 @@ def data_shardings(rules, mesh: Mesh, cfg, kind: str,
 def stacked_cache_pspec_tree(stacked_cache_shapes, rules, mesh: Mesh,
                              seq_axes=None):
     """Shardings for the stacked-expert decode core's cache: every leaf
-    carries the K (``dexpert``) dim at axis 1 — after its scan dim, the
-    transpose-free layout of ``core/ensemble.stack_experts_for_decode`` —
-    sharded over ``pod`` under the decentralized rules, with the per-expert
-    remainder placed exactly as ``cache_pspec_tree`` places the unstacked
-    cache. This makes the vmapped mixture ``decode_step`` one SPMD op whose
-    expert slices stay on their own pods (the serving analogue of
-    zero-communication training).
+    carries the K (``dexpert``) dim where ``core/ensemble.
+    stacked_cache_axes`` puts it — at axis 1, after its scan dim, for a
+    leaf the layer loop scans, and leading a paged pool leaf, which the
+    loop carries whole — sharded over ``pod`` under the decentralized
+    rules, with the per-expert remainder placed exactly as
+    ``cache_pspec_tree`` places the unstacked cache. This makes the
+    vmapped mixture ``decode_step`` one SPMD op whose expert slices stay
+    on their own pods (the serving analogue of zero-communication
+    training).
 
     Pass ``seq_axes`` — the UNSTACKED ``CacheSpec.paged.seq_axes`` pytree —
     when the stacked cache is the paged layout, so pool leaves get their
-    block-pool placement."""
+    block-pool placement and their leading expert dim."""
     import jax
 
-    def strip(s):
-        return jax.ShapeDtypeStruct(s.shape[:1] + s.shape[2:], s.dtype)
+    k_axes = jax.tree.map(lambda _: 1, stacked_cache_shapes) \
+        if seq_axes is None else \
+        jax.tree.map(lambda s: 0 if s >= 0 else 1, seq_axes)
 
-    stripped = jax.tree.map(strip, stacked_cache_shapes)
+    def strip(s, k):
+        return jax.ShapeDtypeStruct(s.shape[:k] + s.shape[k + 1:], s.dtype)
+
+    stripped = jax.tree.map(strip, stacked_cache_shapes, k_axes)
     if seq_axes is None:
         inner = cache_pspec_tree(stripped, rules, mesh)
     else:
         inner = paged_pool_pspec_tree(stripped, rules, mesh, seq_axes)
-    return jax.tree.map(
-        lambda ns: NamedSharding(
-            mesh, P(ns.spec[0] if len(ns.spec) else None,
-                    rules["dexpert"], *ns.spec[1:])), inner)
+
+    def put(ns, k):
+        spec = tuple(ns.spec) + (None,) * max(0, k - len(ns.spec))
+        return NamedSharding(
+            mesh, P(*spec[:k], rules["dexpert"], *spec[k:]))
+
+    return jax.tree.map(put, inner, k_axes)
 
 
 def _cache_leaf_spec(shape_struct, rules, mesh: Mesh) -> P:

@@ -29,6 +29,7 @@ from test_scheduler import make_requests
 
 TOL = {jnp.float32: dict(rtol=2e-5, atol=2e-5),
        jnp.bfloat16: dict(rtol=2e-2, atol=2e-2)}
+LAYERS = 3          # the kernels read one layer of a layer-stacked pool
 
 
 def rand(key, shape, dtype):
@@ -46,17 +47,23 @@ def rand(key, shape, dtype):
     (16, 4, 32, 4, 1, 128, 112),  # MQA, final chunk ends at capacity
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_chunk_prefill_kernel(C, NB, block, H, KV, dh, start, dtype):
+@pytest.mark.parametrize("layer,bps", [(0, 1), (LAYERS - 1, 4)])
+def test_chunk_prefill_kernel(C, NB, block, H, KV, dh, start, dtype, layer,
+                              bps):
+    """The kernel reads ``layer`` of a 3-layer pool (the first and the
+    last) through its scalar-prefetched layer index."""
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     P = NB + 3                            # pool bigger than needed
     q = rand(ks[0], (C, H, dh), dtype)
-    kp = rand(ks[1], (P, KV, block, dh), dtype)
-    vp = rand(ks[2], (P, KV, block, dh), dtype)
+    kp = rand(ks[1], (LAYERS, P, KV, block, dh), dtype)
+    vp = rand(ks[2], (LAYERS, P, KV, block, dh), dtype)
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:NB], jnp.int32)
-    out = chunk_prefill_attention(q, kp, vp, jnp.int32(start), bt,
+    out = chunk_prefill_attention(q, kp, vp, jnp.int32(layer),
+                                  jnp.int32(start), bt, blocks_per_step=bps,
                                   interpret=True)
-    want = ref.chunk_prefill_attention_ref(q, kp, vp, jnp.int32(start), bt)
+    want = ref.chunk_prefill_attention_ref(q, kp, vp, layer,
+                                           jnp.int32(start), bt)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
 
@@ -72,14 +79,15 @@ def test_chunk_prefill_kernel_blocks_per_step(block, NB, start, bps):
     C, H, KV, dh = 5, 8, 4, 32
     P = NB + 2
     q = rand(ks[0], (C, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
-    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
+    kp = rand(ks[1], (1, P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (1, P, KV, block, dh), jnp.float32)
     rng = np.random.default_rng(2)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:NB], jnp.int32)
-    base = chunk_prefill_attention(q, kp, vp, jnp.int32(start), bt,
+    base = chunk_prefill_attention(q, kp, vp, 0, jnp.int32(start), bt,
                                    interpret=True)
-    want = ref.chunk_prefill_attention_ref(q, kp, vp, jnp.int32(start), bt)
-    out = chunk_prefill_attention(q, kp, vp, jnp.int32(start), bt,
+    want = ref.chunk_prefill_attention_ref(q, kp, vp, 0, jnp.int32(start),
+                                           bt)
+    out = chunk_prefill_attention(q, kp, vp, 0, jnp.int32(start), bt,
                                   blocks_per_step=bps, interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -93,12 +101,13 @@ def test_chunk_prefill_ref_row0_is_decode_ref():
     NB, block, H, KV, dh = 4, 8, 4, 2, 32
     P = NB + 1
     q = rand(ks[0], (1, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
-    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
+    kp = rand(ks[1], (LAYERS, P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (LAYERS, P, KV, block, dh), jnp.float32)
     bt = jnp.arange(1, NB + 1, dtype=jnp.int32)
     start = jnp.int32(13)
-    got = ref.chunk_prefill_attention_ref(q, kp, vp, start, bt)
-    want = ref.paged_decode_attention_ref(q, kp, vp, start[None], bt[None])
+    got = ref.chunk_prefill_attention_ref(q, kp, vp, 1, start, bt)
+    want = ref.paged_decode_attention_ref(q, kp, vp, 1, start[None],
+                                          bt[None])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
 

@@ -40,25 +40,33 @@ def rand(key, shape, dtype):
 # Paged decode kernel vs jnp oracle
 # ---------------------------------------------------------------------------
 
+LAYERS = 3          # the kernels read one layer of a layer-stacked pool
+
+
 @pytest.mark.parametrize("B,NB,block,H,KV,dh", [
     (2, 4, 32, 4, 4, 64),     # MHA
     (3, 8, 16, 8, 2, 64),     # GQA 4:1
     (1, 4, 64, 4, 1, 128),    # MQA, MXU-aligned head dim
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_decode_kernel(B, NB, block, H, KV, dh, dtype):
+@pytest.mark.parametrize("layer,bps", [(0, 1), (LAYERS - 1, 4)])
+def test_paged_decode_kernel(B, NB, block, H, KV, dh, dtype, layer, bps):
+    """The kernel reads ``layer`` of a 3-layer pool, the first and the
+    last, through its scalar-prefetched layer index; every other layer
+    holds different values, so a wrong layer cannot pass."""
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     P = B * NB + 3                        # pool bigger than needed
     q = rand(ks[0], (B, H, dh), dtype)
-    kp = rand(ks[1], (P, KV, block, dh), dtype)
-    vp = rand(ks[2], (P, KV, block, dh), dtype)
+    kp = rand(ks[1], (LAYERS, P, KV, block, dh), dtype)
+    vp = rand(ks[2], (LAYERS, P, KV, block, dh), dtype)
     rng = np.random.default_rng(0)
     # distinct physical blocks per slot; block 0 reserved (scratch)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
                      .reshape(B, NB), jnp.int32)
     pos = jax.random.randint(ks[3], (B,), 0, NB * block)
-    out = paged_decode_attention(q, kp, vp, pos, bt, interpret=True)
-    want = ref.paged_decode_attention_ref(q, kp, vp, pos, bt)
+    out = paged_decode_attention(q, kp, vp, jnp.int32(layer), pos, bt,
+                                 blocks_per_step=bps, interpret=True)
+    want = ref.paged_decode_attention_ref(q, kp, vp, layer, pos, bt)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **TOL[dtype])
 
@@ -70,15 +78,15 @@ def test_paged_decode_kernel_ring(pos_vals):
     B, NB, block, H, KV, dh = 2, 4, 16, 4, 2, 64
     P = B * NB + 1
     q = rand(ks[0], (B, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
-    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
+    kp = rand(ks[1], (1, P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (1, P, KV, block, dh), jnp.float32)
     rng = np.random.default_rng(1)
     bt = jnp.asarray(rng.permutation(np.arange(1, P)).reshape(B, NB),
                      jnp.int32)
     pos = jnp.asarray(pos_vals, jnp.int32)
-    out = paged_decode_attention(q, kp, vp, pos, bt, window=NB * block,
+    out = paged_decode_attention(q, kp, vp, 0, pos, bt, window=NB * block,
                                  interpret=True)
-    want = ref.paged_decode_attention_ref(q, kp, vp, pos, bt,
+    want = ref.paged_decode_attention_ref(q, kp, vp, 0, pos, bt,
                                           window=NB * block)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
@@ -96,24 +104,24 @@ def test_paged_decode_kernel_blocks_per_step(block, NB, bps):
     B, H, KV, dh = 3, 8, 4, 32
     P = B * NB + 2
     q = rand(ks[0], (B, H, dh), jnp.float32)
-    kp = rand(ks[1], (P, KV, block, dh), jnp.float32)
-    vp = rand(ks[2], (P, KV, block, dh), jnp.float32)
+    kp = rand(ks[1], (1, P, KV, block, dh), jnp.float32)
+    vp = rand(ks[2], (1, P, KV, block, dh), jnp.float32)
     rng = np.random.default_rng(3)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
                      .reshape(B, NB), jnp.int32)
     # cover empty, mid-block, block-boundary and full horizons
     pos = jnp.asarray([0, block * (NB // 2), NB * block - 1][:B], jnp.int32)
-    base = paged_decode_attention(q, kp, vp, pos, bt, interpret=True)
-    want = ref.paged_decode_attention_ref(q, kp, vp, pos, bt)
-    out = paged_decode_attention(q, kp, vp, pos, bt, blocks_per_step=bps,
+    base = paged_decode_attention(q, kp, vp, 0, pos, bt, interpret=True)
+    want = ref.paged_decode_attention_ref(q, kp, vp, 0, pos, bt)
+    out = paged_decode_attention(q, kp, vp, 0, pos, bt, blocks_per_step=bps,
                                  interpret=True)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(base))
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
     # ring-window variant keeps the whole span live once wrapped
-    outw = paged_decode_attention(q, kp, vp, pos, bt, window=NB * block,
+    outw = paged_decode_attention(q, kp, vp, 0, pos, bt, window=NB * block,
                                   blocks_per_step=bps, interpret=True)
-    wantw = ref.paged_decode_attention_ref(q, kp, vp, pos, bt,
+    wantw = ref.paged_decode_attention_ref(q, kp, vp, 0, pos, bt,
                                            window=NB * block)
     np.testing.assert_allclose(np.asarray(outw), np.asarray(wantw),
                                rtol=2e-5, atol=2e-5)
@@ -128,10 +136,10 @@ def test_paged_ref_equals_contiguous_gather():
     k = rand(ks[1], (B, NB * block, KV, dh), jnp.float32)
     v = rand(ks[2], (B, NB * block, KV, dh), jnp.float32)
     pos = jax.random.randint(ks[3], (B,), 0, NB * block)
-    kp = jnp.swapaxes(k.reshape(B * NB, block, KV, dh), 1, 2)
-    vp = jnp.swapaxes(v.reshape(B * NB, block, KV, dh), 1, 2)
+    kp = jnp.swapaxes(k.reshape(1, B * NB, block, KV, dh), 2, 3)
+    vp = jnp.swapaxes(v.reshape(1, B * NB, block, KV, dh), 2, 3)
     bt = jnp.arange(B * NB, dtype=jnp.int32).reshape(B, NB)
-    got = ref.paged_decode_attention_ref(q, kp, vp, pos, bt)
+    got = ref.paged_decode_attention_ref(q, kp, vp, 0, pos, bt)
     want = ref.decode_attention_ref(q, k, v, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-6, atol=1e-6)
@@ -416,6 +424,7 @@ def test_paged_pool_pspec_layout(arch):
     axes and kv-heads over model; direct leaves keep their contiguous
     placement; the stacked variant carries ``dexpert`` (pod) at axis 1."""
     from jax.sharding import Mesh, PartitionSpec
+    from repro.core.ensemble import stacked_cache_axes
     from repro.sharding.rules import (cache_pspec_tree, logical_rules,
                                       paged_pool_pspec_tree,
                                       stacked_cache_pspec_tree)
@@ -442,13 +451,18 @@ def test_paged_pool_pspec_layout(arch):
 
     jax.tree.map(check, specs, shapes, spec.paged.seq_axes, plain)
 
+    # the stacked pool leaves lead with dexpert; direct leaves carry it
+    # after their scan dim (core/ensemble.stacked_cache_axes)
     K = 2
+    k_axes = stacked_cache_axes(model, True)
     stacked = jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape[:1] + (K,) + s.shape[1:],
-                                       s.dtype), shapes)
+        lambda s, k: jax.ShapeDtypeStruct(s.shape[:k] + (K,) + s.shape[k:],
+                                          s.dtype), shapes, k_axes)
     sspecs = stacked_cache_pspec_tree(stacked, rules, mesh,
                                       spec.paged.seq_axes)
+    assert jax.tree.leaves(k_axes) == [
+        0 if s >= 0 else 1 for s in jax.tree.leaves(spec.paged.seq_axes)]
     jax.tree.map(
-        lambda ns, leaf: np.testing.assert_equal(
-            (tuple(ns.spec) + (None,) * len(leaf.shape))[1], "pod"),
-        sspecs, stacked)
+        lambda ns, leaf, k: np.testing.assert_equal(
+            (tuple(ns.spec) + (None,) * len(leaf.shape))[k], "pod"),
+        sspecs, stacked, k_axes)

@@ -345,28 +345,31 @@ def test_spec_decentralized_top1_parity():
     (2, 4, 16, 4, 4, 64, 3),     # MHA
     (3, 8, 16, 8, 2, 64, 4),     # GQA 4:1
 ])
-@pytest.mark.parametrize("bps", [1, 2])
+@pytest.mark.parametrize("bps", [1, 2, 4])
+@pytest.mark.parametrize("layer", [0, 2])
 def test_paged_verify_kernel_matches_decode_ref(B, NB, block, H, KV, dh,
-                                                L, bps):
+                                                L, bps, layer):
     """Verify row j IS decode attention at position pos + j (the per-row
     causal fence), so the existing paged-decode oracle checks every row
-    of the one-launch span kernel."""
+    of the one-launch span kernel — reading ``layer`` of a 3-layer pool,
+    the first and the last."""
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     P = B * NB + 3
     dt = jnp.float32
     q = jax.random.normal(ks[0], (B, L, H, dh), dt)
-    kp = jax.random.normal(ks[1], (P, KV, block, dh), dt)
-    vp = jax.random.normal(ks[2], (P, KV, block, dh), dt)
+    kp = jax.random.normal(ks[1], (3, P, KV, block, dh), dt)
+    vp = jax.random.normal(ks[2], (3, P, KV, block, dh), dt)
     rng = np.random.default_rng(0)
     bt = jnp.asarray(rng.permutation(np.arange(1, P))[:B * NB]
                      .reshape(B, NB), jnp.int32)
     # span must fit the logical horizon: pos + L - 1 < NB * block
     pos = jax.random.randint(ks[3], (B,), 0, NB * block - L + 1)
-    out = paged_verify_attention(q, kp, vp, pos, bt, blocks_per_step=bps,
-                                 interpret=True)
+    out = paged_verify_attention(q, kp, vp, jnp.int32(layer), pos, bt,
+                                 blocks_per_step=bps, interpret=True)
     assert out.shape == (B, L, H, dh)
     for j in range(L):
-        want = ref.paged_decode_attention_ref(q[:, j], kp, vp, pos + j, bt)
+        want = ref.paged_decode_attention_ref(q[:, j], kp, vp, layer,
+                                              pos + j, bt)
         np.testing.assert_allclose(np.asarray(out[:, j], np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=2e-5, atol=2e-5)
